@@ -420,23 +420,11 @@ def _obs_probe(quick: bool = False):
     return rows, tracked
 
 
-def _setup_jax_cache() -> None:
-    """Point jax at a persistent XLA compilation cache (honours an
-    existing ``JAX_COMPILATION_CACHE_DIR``).  Wall-clock rows then measure
-    the engine, not recompiles of bit-unchanged executables — and repeated
-    bench runs become comparable instead of varying by several seconds of
-    compile noise."""
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro-jax")
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache)
-    except Exception:            # pragma: no cover - old jax / RO home
-        pass
-
-
 def main() -> None:
-    _setup_jax_cache()
+    # compiles land in the persistent cache: wall-clock rows then measure
+    # the engine, not recompiles of bit-unchanged executables
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,

@@ -46,6 +46,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np
 
 from repro import obs as obslib
+from repro.compile_cache import enable_compile_cache
 from repro.sim import make_cluster, make_jobs, simulate
 from repro.sim.scenarios import ALL_SCHEDULERS, SCENARIOS, run_scenario
 
@@ -181,6 +182,7 @@ def main():
                          "JSON with the metrics snapshot embedded — open "
                          "it at https://ui.perfetto.dev")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.profile:
         os.environ["REPRO_DECIDE_PROFILE"] = "1"
     if args.scheduler and args.scenario not in ("scale", "scale10x",

@@ -20,11 +20,14 @@ setting the exact values need future knowledge, so the operator supplies
 * a **device residency** (jax arrays), materialised lazily on the first
   ``device_state()`` call (one full host→device upload, counted in
   ``device_uploads``) and then maintained *incrementally*: each
-  ``commit``/``release`` streams only the committed slot window to the
-  device and applies it with a jit-compiled dense window add (buffers
-  donated off-CPU).  The fused jax engine reads prices directly from
-  this resident state, so a long simulation performs O(1) full-state
-  uploads instead of one per accepted job.
+  ``commit``/``release`` streams only the committed slot window of the
+  updated host mirror to the device and writes it with a jit-compiled
+  dense window store (buffers donated off-CPU).  The residency is thus
+  always the host mirror rounded to the device dtype — bit-equal in
+  float64, never drifting in float32 however many commits and releases
+  it absorbs.  The fused jax engine reads prices directly from this
+  resident state, so a long simulation performs one full-state upload
+  instead of one per accepted job.
 
 Reading the ``g``/``v`` properties hands out the mutable host arrays, so
 it conservatively drops the device residency (the caller may write); the
@@ -185,28 +188,26 @@ def _pool_prices(alloc: np.ndarray, caps: np.ndarray, U: np.ndarray,
 
 
 @functools.lru_cache(maxsize=None)
-def _window_add_jit(donate: bool):
-    """jit'd dense slot-window add: buf[t0:t0+win] += delta (win static per
-    compile via delta's shape, t0 dynamic).  Donated buffers where the
+def _window_set_jit(donate: bool):
+    """jit'd dense slot-window store: buf[t0:t0+win] = vals (win static per
+    compile via vals' shape, t0 dynamic).  Donated buffers where the
     backend supports it (donation on CPU only triggers a warning)."""
     import jax
 
     import jax.numpy as jnp
 
-    def _add(buf, delta, t0):
+    def _set(buf, vals, t0):
         start = (t0,) + (jnp.zeros_like(t0),) * (buf.ndim - 1)
-        cur = jax.lax.dynamic_slice(buf, start, delta.shape)
-        return jax.lax.dynamic_update_slice(buf, cur + delta, start)
+        return jax.lax.dynamic_update_slice(buf, vals, start)
 
-    return jax.jit(_add, donate_argnums=(0,) if donate else ())
+    return jax.jit(_set, donate_argnums=(0,) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
 def _window_roll_jit(donate: bool):
     """jit'd window slide: drop the first ``k`` slots, zero-fill the tail
     (``k`` dynamic, shape static).  Values merely move, so the surviving
-    slots stay bit-equal to their pre-slide selves in any dtype — no
-    resync cadence needed (unlike the f32 incremental adds)."""
+    slots stay bit-equal to their pre-slide selves in any dtype."""
     import jax
 
     import jax.numpy as jnp
@@ -223,8 +224,8 @@ def _x64_if(dtype) -> contextlib.AbstractContextManager:
     """enable_x64 context when the device dtype is float64 (CPU policy) —
     keeps uploads/window ops from being canonicalized down to float32."""
     if np.dtype(dtype) == np.float64:
-        from jax.experimental import enable_x64
-        return enable_x64(True)
+        import jax
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 
@@ -293,7 +294,6 @@ class PriceState:
         self._dev = None
         self._dev_dtype = None
         self._dev_static = {}
-        self._commits_since_sync = 0
         self.device_uploads = 0
 
     # -- rolling window ----------------------------------------------------
@@ -436,30 +436,16 @@ class PriceState:
         if ps and self.cluster.K:
             deltas.append((1, self._v_host) + self._window_delta(
                 ps, sres, T, sign))
-        self._apply_deltas(deltas, negative=sign < 0)
+        self._apply_deltas(deltas)
 
-    def _apply_deltas(self, deltas, negative: bool) -> None:
+    def _apply_deltas(self, deltas) -> None:
         """Common tail of every state mutation (job commits/releases and
         fleet-churn server blocks): host add, incremental device stream,
         version bump, dirty-span logging."""
         for _, host, t0, delta in deltas:
             host[t0:t0 + delta.shape[0]] += delta
         if self._dev is not None and deltas:
-            if np.dtype(self._dev_dtype) != np.float64 and (
-                    negative
-                    or self._commits_since_sync >= self._F32_RESYNC_EVERY):
-                # float32 residency (GPU/TPU): incremental adds round per
-                # commit, so the residency slowly drifts from the float64
-                # mirror, and (g + d) - d is not exact at all, so a
-                # release would leave phantom allocation behind.  Resync
-                # from the mirror on every release (rare: cancellations /
-                # fault handling) and every _F32_RESYNC_EVERY commits —
-                # the drift stays bounded at O(uploads) ~
-                # O(accepts / 256 + cancels), not O(accepted jobs).
-                self._dev = None
-            else:
-                self._device_apply(deltas)
-                self._commits_since_sync += 1
+            self._device_apply(deltas)
         self.version += 1
         for _, _, t0, delta in deltas:
             self._dirty_log.append((self.version, t0, t0 + delta.shape[0]))
@@ -469,16 +455,21 @@ class PriceState:
             del self._dirty_log[:drop]
 
     def _device_apply(self, deltas) -> None:
-        """Stream the slot-window deltas to the resident device arrays."""
+        """Stream the updated host-mirror windows to the resident device
+        arrays.  Storing the mirror's values, not adding the deltas on
+        device, keeps a float32 residency equal to the rounded mirror:
+        float32 adds would round per commit, and (g + d) - d is not
+        exact, so a release would leave phantom allocation behind."""
         import jax
         import jax.numpy as jnp
-        add = _window_add_jit(jax.default_backend() != "cpu")
+        store = _window_set_jit(jax.default_backend() != "cpu")
         dev = list(self._dev)
         with _x64_if(self._dev_dtype):
-            for pool, _, t0, delta in deltas:
-                dev[pool] = add(dev[pool],
-                                jnp.asarray(delta, self._dev_dtype),
-                                np.int32(t0))
+            for pool, host, t0, delta in deltas:
+                # a copy: the mirror is mutated again by the next commit
+                vals = jnp.array(host[t0:t0 + delta.shape[0]],
+                                 self._dev_dtype, copy=True)
+                dev[pool] = store(dev[pool], vals, np.int32(t0))
         self._dev = tuple(dev)
 
     def commit(self, job: Job, workers: dict, ps: dict) -> None:
@@ -527,7 +518,7 @@ class PriceState:
         w0 = T - win
         delta = np.zeros((win, host.shape[1], R))
         delta[t0 - w0:, server, :] = amt
-        self._apply_deltas([(pool_i, host, w0, delta)], negative=False)
+        self._apply_deltas([(pool_i, host, w0, delta)])
         if _obs.ENABLED:
             _obs.inc("price.server_blocks")
         return float(amt[:, 0].sum())
@@ -549,7 +540,7 @@ class PriceState:
         w0 = T - win
         delta = np.zeros((win, host.shape[1], R))
         delta[t0 - w0:, server, :] = -amt
-        self._apply_deltas([(pool_i, host, w0, delta)], negative=True)
+        self._apply_deltas([(pool_i, host, w0, delta)])
         if _obs.ENABLED:
             _obs.inc("price.server_unblocks")
         return float(amt[:, 0].sum())
@@ -628,13 +619,8 @@ class PriceState:
         self._dev_static[key] = sd
         return sd
 
-    # full f32-residency resync cadence (see _apply); f64 never resyncs —
-    # its incremental adds are bit-identical to the mirror's
-    _F32_RESYNC_EVERY = 256
-
     def _upload(self, dtype):
         import jax.numpy as jnp
-        self._commits_since_sync = 0
         g, v = self._g_host, self._v_host
         if g.shape[1] == 0:
             g = np.zeros((self.horizon, 1, R))

@@ -35,11 +35,13 @@ not use.
 price state) in one launch per shape bucket — the speculative half of
 ``OASiS.on_arrivals``.
 
-Precision: on CPU the engine runs under ``jax.experimental.enable_x64``
-by default so its decisions match the float64 numpy/reference paths
-exactly; on TPU it runs float32 (f64 is unsupported there) with the
-Pallas min-plus sweep kernel via the legacy monolithic core
-(``use_pallas=True`` keeps that path compiled and equivalence-tested).
+Precision: on CPU the engine runs under ``jax.enable_x64`` by default so
+its decisions match the float64 numpy/reference paths exactly; on TPU it
+runs float32 (f64 is unsupported there).  Single arrivals on TPU take the
+legacy monolithic core with the Pallas min-plus sweep kernel
+(``use_pallas=True``), which Mosaic compiles whenever the launch is
+lowered for a TPU (``kernels.minplus.ops.minplus_sweep``); bursts take
+the tiled core.
 
 ``dp_sweep_jax`` (the seed's DP-only entry point) is kept for
 micro-benches and backward compatibility.
@@ -57,10 +59,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.minplus.kernel import minplus_sweep_pallas
 from ..kernels.minplus.monotone import (PATH_CHAIN, PATH_DNC, PATH_PLATEAU,
                                         convex_certificate, monotone_dnc_step,
                                         plateau_step_unrolled, run_count)
+from ..kernels.minplus.ops import minplus_sweep
 from ..kernels.minplus.ref import minplus_sweep_cost, minplus_sweep_ref
 from ..kernels.minplus.tiled import TILE, minplus_chain_step
 from .pricing import PriceState, size_bucket as _bucket
@@ -142,8 +144,7 @@ def decide_profile_snapshot() -> dict:
 def _sweep(rows: jax.Array, d_total: int, use_pallas: bool
            ) -> Tuple[jax.Array, jax.Array]:
     if use_pallas:
-        interpret = jax.default_backend() != "tpu"
-        return minplus_sweep_pallas(rows, d_total, interpret=interpret)
+        return minplus_sweep(rows, d_total)
     return minplus_sweep_ref(rows, d_total)
 
 
@@ -767,9 +768,7 @@ def _decide_core(sd, jd, *, d1: int, use_pallas: bool):
 
     # banded min-plus DP over slots (cost only; splits recovered below)
     if use_pallas:
-        cost_tab = minplus_sweep_pallas(
-            rows, d1 - 1, interpret=jax.default_backend() != "tpu")[0]
-        cost_tab = cost_tab.astype(dt)
+        cost_tab = minplus_sweep(rows, d1 - 1)[0].astype(dt)
     else:
         cost_tab = minplus_sweep_cost(rows, d1 - 1)
 
@@ -1119,17 +1118,16 @@ def _x64_context(precision: str):
     the numpy paths), float32 on TPU.  An ambient jax_enable_x64 always wins.
     """
     import contextlib
-    from jax.experimental import enable_x64
     if precision == "x64" or (precision == "auto"
                               and jax.default_backend() == "cpu"):
-        # already-enabled is a no-op: entering enable_x64 flips the
+        # already-enabled is a no-op: entering jax.enable_x64 flips the
         # thread-local config even when the value is unchanged, and every
         # flip knocks jit calls off the C fast path (~ms of python
-        # dispatch per call).  The sim drivers hold one enable_x64 open
+        # dispatch per call).  The sim drivers hold one x64 context open
         # across the whole run so per-decision entries land here.
         if jax.config.jax_enable_x64:
             return contextlib.nullcontext()
-        return enable_x64(True)
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

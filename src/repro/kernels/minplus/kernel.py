@@ -1,240 +1,117 @@
 """Pallas TPU kernel for the banded min-plus (tropical) convolution at the
-heart of the paper's DP subroutine (Alg. 2):
+heart of the paper's DP subroutine (Alg. 2), swept over all T slots in one
+launch:
 
-    new[d]  = min_{d' in [0, DC]} row[d'] + prev[d - d']
-    arg[d]  = argmin_{d'} (same)
+    cost_t[d]  = min_{j in [0, DC]} rows[t, j] + cost_{t-1}[d - j]
+    split_t[d] = first argmin_j (same)
 
 This is the only super-linear term in OASiS (O(T N^2 E^2), Theorem 3) —
 the paper's hot spot.  Min-plus is not a ring matmul, so the MXU cannot
-be used; the kernel targets the VPU with lane-aligned (multiple-of-128)
-blocks.  ``prev`` is small enough (D <= ~32k floats) to live fully in
-VMEM; the output is blocked over d and each block slides a window over
-the left-padded ``prev``.
+be used; the kernel runs on the VPU.
 
-Layout: 2-D (1, L) row vectors — keeps the last dimension on lanes.
+Layout.  The grid walks the T slots in order ("arbitrary" semantics); the
+carried row ``cost_{t-1}`` lives in a VMEM scratch of ``L = d1p + dc1p``
+lanes whose tail ``[d1p, L)`` stays +inf, so a lane rotation by ``j``
+(``pltpu.roll``) yields the tap-``j`` window ``prev[d - j]`` with the
+wrapped-around lanes reading +inf — no dynamic lane slicing, which the
+TPU compiler does not lower.  The 8 sublanes of a vreg hold 8 consecutive
+taps side by side; each keeps its own running first minimum, and the
+final sublane reduction keeps the smallest tap among the equal minima, so
+the argmin is the same first minimum a sequential scan gives.  A tap's
+row value is picked out of the lane-major row by a masked lane
+reduction, not a dynamic lane index.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_D = 512
+_SUB = 8        # float32 sublanes per vreg: taps evaluated side by side
+_LANE = 128
 
 
-def _minplus_kernel(row_ref, prevpad_ref, out_ref, arg_ref, *, dc1: int,
-                    block: int):
-    """row: (1, DCpad); prevpad: (1, D1 + DCpad); out/arg: (1, block)."""
-    i = pl.program_id(0)
-    row = row_ref[0, :]                      # (DCpad,)
-    base = i * block
-    best = jnp.full((block,), jnp.inf, jnp.float32)
-    arg = jnp.zeros((block,), jnp.int32)
-
-    def body(j, carry):
-        best, arg = carry
-        # new[d] = row[j] + prev[d - j]  -> window starts at base + DCpad-... :
-        # prevpad[k] = prev[k - dcpad]; for output offset o in [0, block):
-        #   prev[base + o - j] = prevpad[base + o - j + dcpad]
-        start = base + dc1 - 1 - j
-        window = jax.lax.dynamic_slice(prevpad_ref[0, :], (start,), (block,))
-        cand = row[j] + window
-        take = cand < best
-        return jnp.where(take, cand, best), jnp.where(take, j, arg)
-
-    best, arg = jax.lax.fori_loop(0, dc1, body, (best, arg))
-    out_ref[0, :] = best
-    arg_ref[0, :] = arg
+def _pad_lanes(n: int) -> int:
+    return ((n + _LANE - 1) // _LANE) * _LANE
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def minplus_pallas(row: jax.Array, prev: jax.Array, *, interpret: bool = True):
-    """row: (DC+1,) float32 (+inf for infeasible); prev: (D+1,).
-    Returns (new (D+1,), argmin (D+1,)).  Sizes are padded to 128 lanes."""
-    d1 = prev.shape[0]
-    dc1 = row.shape[0]
-    block = min(BLOCK_D, ((d1 + 127) // 128) * 128)
-    d1p = ((d1 + block - 1) // block) * block
-    # prevpad[k] = prev[k - (dc1-1)]; +inf outside
-    prevpad = jnp.full((1, d1p + dc1 - 1 + block), jnp.inf, jnp.float32)
-    prevpad = jax.lax.dynamic_update_slice(
-        prevpad, prev.astype(jnp.float32)[None, :], (0, dc1 - 1))
-    rowp = row.astype(jnp.float32)[None, :]
-    grid = (d1p // block,)
-    out, arg = pl.pallas_call(
-        functools.partial(_minplus_kernel, dc1=dc1, block=block),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, dc1), lambda i: (0, 0)),
-            pl.BlockSpec((1, prevpad.shape[1]), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, d1p), jnp.float32),
-            jax.ShapeDtypeStruct((1, d1p), jnp.int32),
-        ],
-        interpret=interpret,
-    )(rowp, prevpad)
-    return out[0, :d1], arg[0, :d1]
-
-
-# ---------------------------------------------------------------------------
-# Fused T-slot DP sweep: ONE kernel launch for the whole Alg. 2 recurrence
-#     cost_t[d] = min_{d'} rows[t, d'] + cost_{t-1}[d - d']
-# The grid iterates over slots (sequential "arbitrary" semantics on TPU); the
-# carried row cost_{t-1} lives in a VMEM scratch buffer across grid steps, so
-# the sweep costs one launch instead of T tiny ones under ``lax.scan``.
-# ---------------------------------------------------------------------------
-
-def _minplus_sweep_kernel(rows_ref, out_ref, arg_ref, prev_ref, *, dc1p: int,
-                          d1p: int):
-    """rows block: (1, dc1p); out/arg blocks: (1, d1p); prev scratch holds the
-    left-inf-padded carry: prev[k] = prev_ref[0, k + dc1p - 1]."""
+def _minplus_sweep_kernel(init_ref, rows_ref, out_ref, arg_ref, carry_ref, *,
+                          dc1p: int, d1p: int):
+    """init: (1, L) first carry, +inf from lane D+1 on; rows block
+    (1, dc1p), +inf beyond DC; out/arg blocks (1, d1p); carry scratch
+    (1, L)."""
     t = pl.program_id(0)
 
     @pl.when(t == 0)
     def _init():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, dc1p + d1p), 1)
-        prev_ref[0, :] = jnp.where(lane[0] == dc1p - 1, 0.0, jnp.inf
-                                   ).astype(jnp.float32)
+        carry_ref[...] = init_ref[...]
 
-    row = rows_ref[0, :]                       # (dc1p,), +inf beyond DC
-    best = jnp.full((d1p,), jnp.inf, jnp.float32)
-    arg = jnp.zeros((d1p,), jnp.int32)
+    L = d1p + dc1p
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_SUB, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_SUB, dc1p), 1)
+    row8 = jnp.broadcast_to(rows_ref[...], (_SUB, dc1p))
+    x = jnp.broadcast_to(carry_ref[...], (_SUB, L))
+    # sublane s starts at tap s: win[s, d] = prev[d - s]
+    win = x
+    for s in range(1, _SUB):
+        win = jnp.where(sub == s, pltpu.roll(x, s, 1), win)
 
-    def body(j, carry):
-        best, arg = carry
-        window = jax.lax.dynamic_slice(prev_ref[0, :], (dc1p - 1 - j,), (d1p,))
-        cand = row[j] + window
+    def body(b, c):
+        win, best, arg = c
+        j = b * _SUB + sub                                      # (8, 1)
+        r = jnp.min(jnp.where(lane == j, row8, jnp.inf), axis=1,
+                    keepdims=True)                              # rows[t, j]
+        cand = r + win[:, :d1p]
         take = cand < best
-        return jnp.where(take, cand, best), jnp.where(take, j, arg)
+        return (pltpu.roll(win, _SUB, 1), jnp.where(take, cand, best),
+                jnp.where(take, j, arg))
 
-    best, arg = jax.lax.fori_loop(0, dc1p, body, (best, arg))
-    out_ref[0, :] = best
-    arg_ref[0, :] = arg
-    prev_ref[0, dc1p - 1:dc1p - 1 + d1p] = best     # carry to slot t+1
+    _, best, arg = jax.lax.fori_loop(
+        0, dc1p // _SUB, body,
+        (win, jnp.full((_SUB, d1p), jnp.inf, jnp.float32),
+         jnp.zeros((_SUB, d1p), jnp.int32)))
+    m = jnp.min(best, axis=0, keepdims=True)
+    out_ref[...] = m
+    arg_ref[...] = jnp.min(jnp.where(best == m, arg, dc1p), axis=0,
+                           keepdims=True)
+    carry_ref[:, :d1p] = m                              # carry to slot t+1
 
 
 @functools.partial(jax.jit, static_argnames=("d_total", "interpret"))
-def minplus_sweep_pallas(rows: jax.Array, d_total: int, *,
-                         interpret: bool = True):
+def minplus_sweep_pallas(rows: jax.Array, d_total: int,
+                         init: Optional[jax.Array] = None, *,
+                         interpret: bool = False):
     """rows: (T, DC+1) float32 (+inf infeasible).  Returns
-    (cost (T, D+1) float32, split (T, D+1) int32) for the full DP sweep with
-    init carry [0, inf, ...] — one kernel launch for all T slots."""
+    (cost (T, D+1) float32, split (T, D+1) int32) for the full DP sweep —
+    one kernel launch for all T slots — from the carry ``init`` (D+1,),
+    by default the DP identity [0, inf, ...]."""
     T, dc1 = rows.shape
     d1 = d_total + 1
-    dc1p = ((dc1 + 127) // 128) * 128
-    d1p = ((d1 + 127) // 128) * 128
-    rowsp = jnp.full((T, dc1p), jnp.inf, jnp.float32)
-    rowsp = jax.lax.dynamic_update_slice(
-        rowsp, rows.astype(jnp.float32), (0, 0))
+    dc1p, d1p = _pad_lanes(dc1), _pad_lanes(d1)
+    L = d1p + dc1p
+    rowsp = jnp.full((T, 1, dc1p), jnp.inf, jnp.float32)
+    rowsp = rowsp.at[:, 0, :dc1].set(rows.astype(jnp.float32))
+    if init is None:
+        init = jnp.full((d1,), jnp.inf, jnp.float32).at[0].set(0.0)
+    initp = jnp.full((1, L), jnp.inf, jnp.float32)
+    initp = initp.at[0, :d1].set(init.astype(jnp.float32))
     out, arg = pl.pallas_call(
         functools.partial(_minplus_sweep_kernel, dc1p=dc1p, d1p=d1p),
         grid=(T,),
-        in_specs=[pl.BlockSpec((1, dc1p), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, d1p), lambda i: (i, 0)),
-            pl.BlockSpec((1, d1p), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, d1p), jnp.float32),
-            jax.ShapeDtypeStruct((T, d1p), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, dc1p + d1p), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        in_specs=[pl.BlockSpec((1, L), lambda t: (0, 0)),
+                  pl.BlockSpec((None, 1, dc1p), lambda t: (t, 0, 0))],
+        out_specs=[pl.BlockSpec((None, 1, d1p), lambda t: (t, 0, 0)),
+                   pl.BlockSpec((None, 1, d1p), lambda t: (t, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((T, 1, d1p), jnp.float32),
+                   jax.ShapeDtypeStruct((T, 1, d1p), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, L), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(rowsp)
-    return out[:, :d1], arg[:, :d1]
+    )(initp, rowsp)
+    return out[:, 0, :d1], arg[:, 0, :d1]
 
-
-# ---------------------------------------------------------------------------
-# Run-compressed (plateau) slot: the Pallas variant of the monotone path.
-# Real COST_t rows are staircases (see kernels/minplus/monotone.py), so the
-# row collapses into L bitwise-equal runs; each run's best candidate is its
-# constant plus a window minimum of the carry, served from a power-of-two
-# doubling table in VMEM scratch — O((D + DC) * (L + log DC)) VPU work
-# instead of the chain's O(D * DC), bit-exact for any row (monotonicity of
-# rounding: fl(c + min prev) == min fl(c + prev) for a constant c).
-# ---------------------------------------------------------------------------
-
-def _minplus_plateau_kernel(row_ref, prevpad_ref, out_ref, tab_ref, *,
-                            dc1p: int, d1p: int, kmax: int, r_max: int):
-    """row: (1, dc1p); prevpad: (1, dc1p + d1p) left-inf-padded carry;
-    out: (1, d1p); tab scratch: (kmax, dc1p + d1p) doubling table with
-    tab[k][i] = min prevpad[i : i + 2^k]."""
-    row = row_ref[0, :]
-    tab_ref[0, :] = prevpad_ref[0, :]
-    for k in range(1, kmax):
-        s = 1 << (k - 1)
-        lvl = tab_ref[k - 1, :]
-        shifted = jnp.concatenate(
-            [lvl[s:], jnp.full((s,), jnp.inf, jnp.float32)])
-        tab_ref[k, :] = jnp.minimum(lvl, shifted)
-
-    js = jax.lax.broadcasted_iota(jnp.int32, (1, dc1p), 1)[0]
-    neq = jnp.concatenate(
-        [jnp.zeros((1,), jnp.bool_), row[1:] != row[:-1]])
-    rid = jnp.cumsum(neq.astype(jnp.int32))
-    n_runs = rid[dc1p - 1] + 1
-
-    def run(w, best):
-        mask = rid == w
-        s_w = jnp.min(jnp.where(mask, js, dc1p))
-        e_w = jnp.max(jnp.where(mask, js, -1))
-        c_w = jnp.min(jnp.where(mask, row, jnp.inf))
-        kw = 31 - jax.lax.clz(jnp.maximum(e_w - s_w + 1, 1))
-        # window min of prevpad[d + dc1p - e_w : d + dc1p - s_w + 1] as
-        # two (overlapping) power-of-two slices of level kw
-        lo = jax.lax.dynamic_slice(
-            tab_ref[...], (kw, dc1p - e_w), (1, d1p))[0]
-        hi = jax.lax.dynamic_slice(
-            tab_ref[...], (kw, dc1p - s_w - (1 << kw) + 1), (1, d1p))[0]
-        cand = c_w + jnp.minimum(lo, hi)
-        return jnp.minimum(best, jnp.where(w < n_runs, cand, jnp.inf))
-
-    out_ref[0, :] = jax.lax.fori_loop(
-        0, r_max, run, jnp.full((d1p,), jnp.inf, jnp.float32))
-
-
-@functools.partial(jax.jit, static_argnames=("r_max", "interpret"))
-def minplus_plateau_pallas(row: jax.Array, prev: jax.Array, *,
-                           r_max: int = 16, interpret: bool = True):
-    """row: (DC+1,) float32 (+inf infeasible); prev: (D+1,).  Returns
-    ``new (D+1,)`` — cost-only, no argmin (the engine backtracks from
-    stored DP columns, not per-slot args).  ONLY sound when ``row`` has
-    at most ``r_max`` maximal runs of bitwise-equal values; the caller
-    gates on :func:`repro.kernels.minplus.monotone.run_count`.  Lane
-    padding appends one +inf run, which is accounted for internally."""
-    d1 = prev.shape[0]
-    dc1 = row.shape[0]
-    dc1p = ((dc1 + 127) // 128) * 128
-    d1p = ((d1 + 127) // 128) * 128
-    rowp = jnp.full((1, dc1p), jnp.inf, jnp.float32)
-    rowp = jax.lax.dynamic_update_slice(
-        rowp, row.astype(jnp.float32)[None, :], (0, 0))
-    prevpad = jnp.full((1, dc1p + d1p), jnp.inf, jnp.float32)
-    prevpad = jax.lax.dynamic_update_slice(
-        prevpad, prev.astype(jnp.float32)[None, :], (0, dc1p))
-    kmax = (dc1p - 1).bit_length() + 1 if dc1p > 1 else 1
-    r_eff = r_max + (1 if dc1p > dc1 else 0)
-    out, = pl.pallas_call(
-        functools.partial(_minplus_plateau_kernel, dc1p=dc1p, d1p=d1p,
-                          kmax=kmax, r_max=r_eff),
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, dc1p), lambda i: (0, 0)),
-            pl.BlockSpec((1, dc1p + d1p), lambda i: (0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, d1p), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, d1p), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((kmax, dc1p + d1p), jnp.float32)],
-        interpret=interpret,
-    )(rowp, prevpad)
-    return out[0, :d1]

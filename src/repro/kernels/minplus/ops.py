@@ -1,47 +1,35 @@
-"""Jit'd entry point: Pallas on TPU, interpret-mode elsewhere, with the
-pure-jnp reference available for oracle checks."""
+"""Entry points: the Pallas kernel, compiled by Mosaic when the program is
+lowered for a TPU and run by the Pallas interpreter on any other platform,
+with the pure-jnp reference for oracle checks."""
 from __future__ import annotations
 
-import jax
+from typing import Optional
 
-from .kernel import (minplus_pallas, minplus_plateau_pallas,
-                     minplus_sweep_pallas)
-from .monotone import monotone_step, run_count_np
-from .ref import minplus_ref, minplus_sweep_ref
+import jax
+import jax.numpy as jnp
+
+from .kernel import minplus_sweep_pallas
+from .ref import minplus_ref
+
+
+def minplus_sweep(rows: jax.Array, d_total: int,
+                  init: Optional[jax.Array] = None):
+    """Full T-slot DP sweep (:func:`minplus_sweep_pallas`): one kernel
+    launch with the carry row in VMEM scratch.  The choice between Mosaic
+    and the interpreter is made at lowering time from the target platform,
+    so a program compiled for the chip always holds the kernel."""
+    def run(interpret):
+        return lambda r, i: minplus_sweep_pallas(r, d_total, i,
+                                                 interpret=interpret)
+    if init is None:
+        init = jnp.full((d_total + 1,), jnp.inf, jnp.float32).at[0].set(0.0)
+    return jax.lax.platform_dependent(rows, init, tpu=run(False),
+                                      default=run(True))
 
 
 def minplus(row: jax.Array, prev: jax.Array, use_pallas: bool = True):
+    """One slot ``new[d] = min_j row[j] + prev[d-j]``; returns (new, argmin)."""
     if not use_pallas:
         return minplus_ref(row, prev)
-    interpret = jax.default_backend() != "tpu"
-    return minplus_pallas(row, prev, interpret=interpret)
-
-
-def minplus_sweep(rows: jax.Array, d_total: int, use_pallas: bool = True):
-    """Full T-slot DP sweep.  Pallas: one kernel launch with the carry row in
-    VMEM scratch; ref: a ``lax.scan`` of per-slot min-plus convolutions."""
-    if not use_pallas:
-        return minplus_sweep_ref(rows, d_total)
-    interpret = jax.default_backend() != "tpu"
-    return minplus_sweep_pallas(rows, d_total, interpret=interpret)
-
-
-def minplus_monotone(row: jax.Array, prev: jax.Array,
-                     use_pallas: bool = True, r_max: int = 16):
-    """Structure-aware min-plus slot ``new[d] = min_j row[j] + prev[d-j]``
-    (cost-only — no argmin).
-
-    Non-Pallas: the full jnp dispatcher from ``monotone.py``
-    (certified-convex D&C / run-compressed plateau / chain fallback).
-    Pallas: the run-compressed plateau kernel when the row compresses
-    into at most ``r_max`` runs (checked host-side — this entry is
-    eager, like a decision-time call), else the chain kernel.  Every
-    path is bit-identical to ``minplus(...)``'s cost output."""
-    if not use_pallas:
-        return monotone_step(row, prev)
-    interpret = jax.default_backend() != "tpu"
-    import numpy as np
-    if int(run_count_np(np.asarray(row))) <= r_max:
-        return minplus_plateau_pallas(row, prev, r_max=r_max,
-                                      interpret=interpret)
-    return minplus_pallas(row, prev, interpret=interpret)[0]
+    cost, arg = minplus_sweep(row[None, :], prev.shape[0] - 1, prev)
+    return cost[0], arg[0]

@@ -27,6 +27,7 @@ import jax
 from ..ckpt import checkpoint as ckpt
 from ..core.types import Schedule
 from ..data.pipeline import DataConfig, DataPipeline
+from ..launch.mesh import make_mesh
 
 
 @dataclasses.dataclass
@@ -70,7 +71,7 @@ class ElasticTrainer:
         for slot in plan:
             width = dp_width(slot.n_workers, len(jax.devices()))
             self.mesh_history.append(width)
-            mesh = jax.make_mesh((width, 1), ("data", "model"))
+            mesh = make_mesh((width, 1), ("data", "model"))
             fn, p_shard, o_shard = self.make_step(mesh)
             params = jax.device_put(params, p_shard)
             opt_state = jax.device_put(opt_state, o_shard)

@@ -27,7 +27,7 @@ ops over dense per-job state:
   (``PriceState.gpu_slot_usage``), and capacity feasibility is one
   whole-state comparison (``PriceState.capacity_ok``) instead of a
   per-slot Python walk.  On ``impl="jax"`` the price state is
-  device-resident with commits streamed as slot-window adds, so the whole
+  device-resident with commits streamed as slot-window stores, so the whole
   run performs O(1) full host↔device syncs (``PriceState.device_uploads``).
 
 On cancellation-free, unperturbed workloads the engine is equivalence-
@@ -454,8 +454,7 @@ def _x64_run(impl: str, decide: bool):
     import jax
     if jax.default_backend() != "cpu" or jax.config.jax_enable_x64:
         return contextlib.nullcontext()
-    from jax.experimental import enable_x64
-    return enable_x64(True)
+    return jax.enable_x64(True)
 
 
 def _drive_oasis(cluster: ClusterSpec, jobs: Sequence[Job],

@@ -284,14 +284,13 @@ def test_dp_sweep_jax_respects_x64():
     """dp_sweep_jax keeps float64 when jax_enable_x64 is on (the seed cast
     everything to float32, silently diverging near ties)."""
     import jax
-    from jax.experimental import enable_x64
     rng = np.random.default_rng(1)
     rows = rng.random((6, 5))
     rows[:, 0] = 0.0
     # values differing only at 1e-9 — indistinguishable in float32
     rows[2, 1] = 0.5
     rows[3, 1] = 0.5 + 1e-9
-    with enable_x64(True):
+    with jax.enable_x64(True):
         cost, split = dp_sweep_jax(rows, 8)
     prev = np.full(9, np.inf)
     prev[0] = 0.0

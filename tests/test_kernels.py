@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.minplus.kernel import minplus_pallas, minplus_sweep_pallas
+from repro.kernels.minplus.kernel import minplus_sweep_pallas
+from repro.kernels.minplus.ops import minplus
 from repro.kernels.minplus.ref import minplus_ref, minplus_sweep_ref
 from repro.kernels.ssd.ops import ssd_op
 from repro.kernels.ssd.ref import ssd_ref
@@ -47,7 +48,7 @@ def test_minplus_sweep(d1, dc1, inf_frac):
     row[rng.random(dc1) < inf_frac] = np.inf
     prev[0] = 0.0
     row[0] = 0.0
-    o1, a1 = minplus_pallas(jnp.array(row), jnp.array(prev), interpret=True)
+    o1, a1 = minplus(jnp.array(row), jnp.array(prev))     # the sweep kernel at T=1
     o2, a2 = minplus_ref(jnp.array(row), jnp.array(prev))
     v1, v2 = np.asarray(o1), np.asarray(o2)
     assert np.all((np.isinf(v1) & np.isinf(v2)) | (np.abs(v1 - v2) < 1e-5))

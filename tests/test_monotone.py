@@ -168,31 +168,12 @@ def test_plateau_unrolled_matches_chain(r_max):
     assert np.array_equal(got, _chain(row, prev))
 
 
-@pytest.mark.parametrize("dc1,d1", [(9, 33), (65, 129), (130, 200)])
-def test_plateau_pallas_matches_chain(dc1, d1):
-    """The run-compressed Pallas kernel (doubling-table window minima)
-    == chain, bit for bit, including the +inf lane-padding run and
-    non-128-multiple shapes."""
-    from repro.kernels.minplus.kernel import minplus_plateau_pallas
-    rng = np.random.default_rng(dc1 + d1)
-    row = np.repeat(rng.random(8), (dc1 + 7) // 8)[:dc1].astype(np.float32)
-    row[0] = 0.0
-    row[-3:] = np.inf                    # infeasible tail = one more run
-    prev = rng.random(d1).astype(np.float32)
-    prev[rng.random(d1) < 0.3] = np.inf
-    prev[0] = 0.0
-    assert int(run_count_np(row)) <= 16
-    got = np.asarray(minplus_plateau_pallas(
-        jnp.asarray(row), jnp.asarray(prev), r_max=16, interpret=True))
-    assert np.array_equal(got, _chain(row, prev))
-
-
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_minplus_monotone_dispatch_equals_minplus(use_pallas):
-    """ops.minplus_monotone == ops.minplus cost output on every row kind
-    (the Pallas entry host-gates on run count; the jnp entry runs the
-    full dispatcher)."""
-    from repro.kernels.minplus.ops import minplus, minplus_monotone
+    """The monotone dispatcher == ops.minplus cost output on every row
+    kind, for both chain entries (the Pallas kernel and the jnp
+    reference)."""
+    from repro.kernels.minplus.ops import minplus
     rng = np.random.default_rng(21)
     for kind in KINDS:
         row = _mk_row(kind, rng, 40, np.float32)
@@ -200,9 +181,7 @@ def test_minplus_monotone_dispatch_equals_minplus(use_pallas):
         prev[0] = 0.0
         want = np.asarray(minplus(jnp.asarray(row), jnp.asarray(prev),
                                   use_pallas=use_pallas)[0])
-        got = np.asarray(minplus_monotone(jnp.asarray(row),
-                                          jnp.asarray(prev),
-                                          use_pallas=use_pallas))
+        got = np.asarray(monotone_step(jnp.asarray(row), jnp.asarray(prev)))
         assert np.array_equal(got, want), kind
 
 

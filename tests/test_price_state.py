@@ -137,6 +137,32 @@ def test_device_mirror_consistent_after_interleaved_commits():
     assert np.array_equal(np.asarray(dev[1]), state._v_host)
 
 
+def test_float32_residency_is_the_rounded_mirror():
+    """A float32 residency (the TPU's) stays exactly the float64 mirror
+    rounded to float32 through commits and releases of non-dyadic
+    demands — no drift, no phantom allocation after a release — and is
+    never re-uploaded."""
+    rng = np.random.default_rng(7)
+    state = _state()
+    T, H, K = state.cluster.T, state.cluster.H, state.cluster.K
+    state.device_state(np.float32)
+    trace = []
+    for jid in range(30):
+        job = _mk_job(jid, rng.uniform(0.1, 3.0, 5), rng.uniform(0.1, 3.0, 5))
+        workers = _alloc(rng, T, H, int(rng.integers(1, T)))
+        ps = _alloc(rng, T, K, int(rng.integers(1, T)))
+        state.commit(job, workers, ps)
+        trace.append((job, workers, ps))
+    for job, workers, ps in trace[::3]:
+        state.release(job, workers, ps)
+    dev = state.device_state(np.float32)
+    assert state.device_uploads == 1
+    assert np.array_equal(np.asarray(dev[0]),
+                          state._g_host.astype(np.float32))
+    assert np.array_equal(np.asarray(dev[1]),
+                          state._v_host.astype(np.float32))
+
+
 def test_jax_impl_run_is_o1_uploads():
     """The tentpole claim: a whole impl="jax" simulation performs O(1)
     full host→device state syncs, not one per accepted job."""

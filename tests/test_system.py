@@ -55,8 +55,9 @@ import sys; sys.path.insert(0, "src")
 import jax
 from repro.configs import get_smoke
 from repro.launch.dryrun import lower_cell
-mesh = jax.make_mesh((2, 4), ("data", "model"))
-mesh_m = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
+mesh_m = make_mesh((2, 2, 2), ("pod", "data", "model"))
 for m in (mesh, mesh_m):
     for kind, seq, gb in [("train", 64, 8), ("decode", 64, 8)]:
         r = lower_cell(get_smoke("olmoe_1b_7b"), "t", seq, gb, kind, m)

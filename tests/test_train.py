@@ -133,9 +133,10 @@ def test_grad_accum_matches_single_step():
     """Microbatched gradient accumulation == single-shot step (bitwise-
     tight in fp32): same params, same metrics, any k dividing the batch."""
     import jax
+    from repro.launch.mesh import make_mesh
     from repro.train.steps import make_train_step
     cfg = TINY
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt_cfg = OptConfig(lr=1e-3)
     params = init_model(jax.random.PRNGKey(0), cfg)
     opt = init_opt(params, opt_cfg)
