@@ -20,25 +20,6 @@ from repro.sim import (make_cluster, make_jobs, scenarios, simulate,
 SCHEDULERS = ["oasis", "fifo", "drf", "rrh", "dorm"]
 
 
-def _stage_profiling_reset() -> bool:
-    """True (and reset the accumulators) iff the fused engine's
-    per-stage decision profiling is on (``REPRO_DECIDE_PROFILE=1``).
-    The stage breakdown then lands in the tracked record as a
-    ``decision.stages`` sub-record — diagnostic only, since profiling
-    re-runs each DP launch and roughly doubles decision latency."""
-    import os
-    if os.environ.get("REPRO_DECIDE_PROFILE", "") in ("", "0"):
-        return False
-    from repro.core.schedule_jax import decide_profile_reset
-    decide_profile_reset()
-    return True
-
-
-def _stage_profile_snapshot() -> dict:
-    from repro.core.schedule_jax import decide_profile_snapshot
-    return decide_profile_snapshot()
-
-
 def fig3_total_utility(T: int = 100, H: int = 20, K: int = 20,
                        sizes=(20, 40, 60, 80)) -> List[str]:
     rows = []
@@ -198,7 +179,6 @@ def fig3_scale(quick: bool = False, include_oasis: bool = False,
     rows = []
     if dims is None:
         dims = scenarios.SCALE_DIMS_QUICK if quick else scenarios.SCALE_DIMS
-    profiling = _stage_profiling_reset()
     results = scenarios.run_scale(seed=0, quick=quick, schedulers=scheds,
                                   T=dims["T"], H=dims["H"], K=dims["K"],
                                   n=dims["n"])
@@ -215,8 +195,6 @@ def fig3_scale(quick: bool = False, include_oasis: bool = False,
                                   "mean": r.decision_mean,
                                   "p95": r.decision_p95}
                     for r in results if r.decision_p50 is not None}
-        if profiling:
-            decision["stages"] = _stage_profile_snapshot()
         stats_out.update({
             "T": dims["T"], "H": dims["H"], "K": dims["K"],
             "n_jobs": dims["n"], "quick": bool(quick),
@@ -289,7 +267,6 @@ def serving_table(quick: bool = False,
     usual wall clock / utility / decision-latency columns.  ``stats_out``
     receives the ``serving`` (or, under ``quick``, ``serving_quick``)
     record for BENCH_decision.json."""
-    profiling = _stage_profiling_reset()
     results = scenarios.run_serving(seed=0, quick=quick)
     rows = []
     for r in results:
@@ -307,8 +284,6 @@ def serving_table(quick: bool = False,
                                   "mean": r.decision_mean,
                                   "p95": r.decision_p95}
                     for r in results if r.decision_p50 is not None}
-        if profiling:
-            decision["stages"] = _stage_profile_snapshot()
         stats_out.update({
             "H": dims["H"], "K": dims["K"], "window": dims["window"],
             "slots": dims["slots"],

@@ -41,11 +41,7 @@ snapshot plus derived health figures (row-cache hit rate, early-exit
 tile fraction, device uploads, preemptions) — the derived leaves are
 regression-gated so a silent efficiency loss (cache stops hitting,
 early exit stops firing, uploads reappear on the commit path) fails CI
-even when wall clocks stay within ratio.  Under ``REPRO_DECIDE_PROFILE=1``
-the ``simscale``/``serving`` sections additionally record the fused
-engine's per-stage wall clock (row build / DP sweep / backtrack /
-placement) as a ``decision.stages`` sub-record — diagnostic only
-(profiling re-runs each DP launch).  Sections *merge* into an
+even when wall clocks stay within ratio.  Sections *merge* into an
 existing ``--json`` file, so
 the committed baseline can accumulate all records; CI regenerates the
 file and fails on >2x regressions via

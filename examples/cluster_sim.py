@@ -56,22 +56,6 @@ def bar(v, vmax, width=40):
     return "#" * n
 
 
-def print_decide_profile():
-    """Stage breakdown accumulated by the fused engine under
-    ``REPRO_DECIDE_PROFILE=1`` (see ``core.schedule_jax
-    .decide_profile_snapshot`` — profiling re-runs the DP launch to
-    split row build from the sweep, so latencies roughly double)."""
-    from repro.core.schedule_jax import decide_profile_snapshot
-    snap = decide_profile_snapshot()
-    n = max(snap.get("decisions", 0.0), 1.0)
-    print("\n== decision stage breakdown "
-          f"({int(n)} fused decisions; REPRO_DECIDE_PROFILE) ==")
-    for stage in ("row_build", "dp_sweep", "backtrack", "placement"):
-        tot = snap.get(stage, 0.0)
-        print(f"{stage:10s} {tot:8.2f}s total  "
-              f"{tot / n * 1e3:8.2f}ms/decision")
-
-
 def run_figs(args):
     summaries = {}
     gaps = {}
@@ -171,11 +155,6 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--quick", action="store_true",
                     help="shrink the scenario instance")
-    ap.add_argument("--profile", action="store_true",
-                    help="record per-stage decision wall clock in the "
-                         "fused engine (row build / DP sweep / backtrack "
-                         "/ placement) and print the breakdown; roughly "
-                         "doubles decision latency")
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record the run with the flight recorder "
                          "(repro.obs) and write a Chrome-trace / Perfetto "
@@ -183,8 +162,6 @@ def main():
                          "it at https://ui.perfetto.dev")
     args = ap.parse_args()
     enable_compile_cache()
-    if args.profile:
-        os.environ["REPRO_DECIDE_PROFILE"] = "1"
     if args.scheduler and args.scenario not in ("scale", "scale10x",
                                                 "serving"):
         ap.error("--scheduler only applies to --scenario "
@@ -206,8 +183,6 @@ def main():
               f"({len(snap['counters'])} counters, "
               f"{len(snap['histograms'])} histograms embedded)")
         obslib.disable()
-    if args.profile:
-        print_decide_profile()
 
 
 if __name__ == "__main__":

@@ -148,7 +148,8 @@ class OASiS:
                     sched = None
                     t0 = time.perf_counter()
                     if pend.best_t >= 0:
-                        sd = _state_arrays(self.state, dtype)
+                        with _obs.span("decide.prep", jid=jobs[i].jid):
+                            sd = _state_arrays(self.state, dtype)
                         sched = _materialize(pend, self.state, sd, dtype)
                     self.decision_seconds.append(
                         times[pos] + time.perf_counter() - t0)

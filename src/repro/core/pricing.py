@@ -196,11 +196,11 @@ def _window_set_jit(donate: bool):
 
     import jax.numpy as jnp
 
-    def _set(buf, vals, t0):
+    def _window_set(buf, vals, t0):
         start = (t0,) + (jnp.zeros_like(t0),) * (buf.ndim - 1)
         return jax.lax.dynamic_update_slice(buf, vals, start)
 
-    return jax.jit(_set, donate_argnums=(0,) if donate else ())
+    return jax.jit(_window_set, donate_argnums=(0,) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
@@ -212,12 +212,12 @@ def _window_roll_jit(donate: bool):
 
     import jax.numpy as jnp
 
-    def _slide(buf, k):
+    def _window_roll(buf, k):
         rolled = jnp.roll(buf, -k, axis=0)
         idx = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0)
         return jnp.where(idx < buf.shape[0] - k, rolled, 0)
 
-    return jax.jit(_slide, donate_argnums=(0,) if donate else ())
+    return jax.jit(_window_roll, donate_argnums=(0,) if donate else ())
 
 
 def _x64_if(dtype) -> contextlib.AbstractContextManager:
@@ -346,9 +346,12 @@ class PriceState:
         if self._dev is not None:
             import jax
             slide = _window_roll_jit(jax.default_backend() != "cpu")
+            dev = []
             with _x64_if(self._dev_dtype):
-                self._dev = tuple(slide(buf, np.int32(k))
-                                  for buf in self._dev)
+                for buf in self._dev:
+                    with _obs.dispatch("_window_roll"):
+                        dev.append(slide(buf, np.int32(k)))
+            self._dev = tuple(dev)
         self.version += 1
         # a slide remaps every local slot index — caches from before it
         # cannot be patched span-wise, only rebuilt
@@ -469,7 +472,8 @@ class PriceState:
                 # a copy: the mirror is mutated again by the next commit
                 vals = jnp.array(host[t0:t0 + delta.shape[0]],
                                  self._dev_dtype, copy=True)
-                dev[pool] = store(dev[pool], vals, np.int32(t0))
+                with _obs.dispatch("_window_set"):
+                    dev[pool] = store(dev[pool], vals, np.int32(t0))
         self._dev = tuple(dev)
 
     def commit(self, job: Job, workers: dict, ps: dict) -> None:

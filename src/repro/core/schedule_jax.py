@@ -69,6 +69,13 @@ from .pricing import PriceState, size_bucket as _bucket
 from .types import Job, R, Schedule
 from .. import obs as _obs
 
+# JAX's persistent compilation cache keys a program without its metadata
+# by default, so an executable compiled from another version of this
+# source is served with that version's ``op_name`` scopes, and a profile
+# credits its device time to stages this source does not have.  Key on
+# the metadata too: the stage scopes below are what the profiles read.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 # Stand-in for "unbounded" per-server instance capacity (job has no demand
 # on some resource): big enough to never bind, small enough that prefix sums
 # of it stay exact-ish in f32 comparisons against tiny instance counts.
@@ -99,41 +106,6 @@ _SPLIT_TOL = 1e-12
 # while parallel backends get real fusion.  Override with REPRO_BURST_LANES.
 _MAX_LANES = int(os.environ.get(
     "REPRO_BURST_LANES", "8" if jax.default_backend() == "tpu" else "1"))
-
-
-# ---------------------------------------------------------------------------
-# Decision-phase stage profiling (REPRO_DECIDE_PROFILE=1)
-# ---------------------------------------------------------------------------
-
-_PROFILE_STAGES = ("row_build", "dp_sweep", "backtrack", "placement")
-_profile_acc = {k: 0.0 for k in _PROFILE_STAGES}
-_profile_acc["decisions"] = 0.0
-
-
-def _profiling() -> bool:
-    """Re-read the environment per launch so callers (e.g.
-    ``examples/cluster_sim.py --profile``) can toggle profiling after
-    this module is imported."""
-    return os.environ.get("REPRO_DECIDE_PROFILE", "") not in ("", "0")
-
-
-def decide_profile_reset() -> None:
-    for k in _profile_acc:
-        _profile_acc[k] = 0.0
-
-
-def decide_profile_snapshot() -> dict:
-    """Accumulated per-stage decision wall clock since the last reset.
-
-    Stages: ``row_build`` (COST-row construction inside the decide
-    launch), ``dp_sweep`` (min-plus DP + early-exit loop), ``backtrack``
-    (split recovery for accepts), ``placement`` (greedy fills).  The
-    row/DP split is measured by re-running the decide launch with every
-    visited tile served from the just-refreshed row cache — the second
-    launch is DP-only, so ``row_build = total - dp_only``.  Profiling
-    therefore roughly doubles decision latency; it is a diagnostic mode,
-    not a benchmark mode."""
-    return dict(_profile_acc)
 
 
 # ---------------------------------------------------------------------------
@@ -283,6 +255,7 @@ def _greedy_cost_b(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
 # ---------------------------------------------------------------------------
 
 @jax.jit
+@jax.named_scope("decide.rows")
 def _sorted_fill_lanes(p, q, g, v, wcaps, scaps, resbw):
     """Full sorted-order/cumsum table set for every lane: 6 arrays
     (B, T_pad, H|K) — ``(w_scost, w_ccap, w_ccost, s_scost, s_ccap,
@@ -300,6 +273,7 @@ def _sorted_fill_lanes(p, q, g, v, wcaps, scaps, resbw):
 
 
 @functools.partial(jax.jit, static_argnames=("span",))
+@jax.named_scope("decide.rows")
 def _sorted_fill(tabs, p, q, g, v, wcaps, scaps, resbw, t0, span: int):
     """Patch one dirty slot span of a single lane's (T_pad, S) table set
     in place — the exact ``_sorted_fill_lanes`` formulas on the span's
@@ -397,6 +371,11 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
     d_slots (B, T_pad) i32, rows (B, T_pad, M) — the refreshed row cache —
     k0, k_end i32: the visited tile range [k0, k_end), paths (3,) i32 —
     per-branch processed-tile counts [dnc, plateau, chain]).
+
+    Device scopes: each tile's COST rows (built or served from the row
+    cache) run under ``decide.rows``; the rest of the tile loop — its
+    exit test and live cost floor, the slot scan with its payoff pick,
+    the buffer writes — under ``decide.dp``.
     """
     g, v, wcaps, scaps, U1, U2, L1, L2, pmin, p_pad, q_pad = sd
     resbw, WZ, u, usmax, meta, lb = jd
@@ -499,19 +478,21 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
     # first tile) instead of sweeping the DP to the deadline.  ``pmin``
     # (the per-slot minimum worker price, (T_pad, R)) is computed once
     # per state version in ``_pad_state``, not per launch.
-    wslot = jnp.einsum("tr,br->bt", pmin, wres)
-    ts_all = jnp.arange(T_pad, dtype=jnp.int32)
-    feas_t = (ts_all[None, :] >= a[:, None]) & (ts_all < T)[None, :]
-    wsort = jnp.sort(jnp.where(feas_t, wslot, jnp.inf), axis=1)  # (B, T_pad)
-    dcap_f = jnp.maximum(dcap, 1).astype(dt)
-    take = jnp.clip(d_tot[:, None].astype(dt)
-                    - ts_all[None, :].astype(dt) * dcap_f[:, None],
-                    0.0, dcap_f[:, None])
-    # infeasible-window tail: missing slots contribute 0, keeping the
-    # floor a valid (weaker) lower bound; the DP itself rejects such jobs
-    floor_sum = jnp.sum(
-        take * jnp.where(jnp.isfinite(wsort), wsort, 0.0), axis=1)
-    lb = jnp.where(lb > 0, lb * floor_sum, 0.0)
+    with jax.named_scope("decide.dp"):
+        wslot = jnp.einsum("tr,br->bt", pmin, wres)
+        ts_all = jnp.arange(T_pad, dtype=jnp.int32)
+        feas_t = (ts_all[None, :] >= a[:, None]) & (ts_all < T)[None, :]
+        wsort = jnp.sort(jnp.where(feas_t, wslot, jnp.inf), axis=1)
+        dcap_f = jnp.maximum(dcap, 1).astype(dt)
+        take = jnp.clip(d_tot[:, None].astype(dt)
+                        - ts_all[None, :].astype(dt) * dcap_f[:, None],
+                        0.0, dcap_f[:, None])
+        # infeasible-window tail: missing slots contribute 0, keeping the
+        # floor a valid (weaker) lower bound; the DP itself rejects such
+        # jobs
+        floor_sum = jnp.sum(
+            take * jnp.where(jnp.isfinite(wsort), wsort, 0.0), axis=1)
+        lb = jnp.where(lb > 0, lb * floor_sum, 0.0)
 
     def cond(c):
         k, _, best, _, _, _, _ = c
@@ -524,16 +505,17 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         k, prev, best, best_t, paths, cost_buf, rows_buf = c
         t0 = k * TILE
         zero = jnp.zeros_like(t0)
-        if use_cache:
-            tile_ok = jnp.all(
-                jax.lax.dynamic_slice_in_dim(valid_tiles, k, 1, axis=1))
-            rows_tile = jax.lax.cond(
-                tile_ok,
-                lambda: jax.lax.dynamic_slice(
-                    rows_init, (zero, t0, zero), (B, TILE, M)),
-                lambda: rows_for_tile(t0))
-        else:
-            rows_tile = rows_for_tile(t0)
+        with jax.named_scope("decide.rows"):
+            if use_cache:
+                tile_ok = jnp.all(
+                    jax.lax.dynamic_slice_in_dim(valid_tiles, k, 1, axis=1))
+                rows_tile = jax.lax.cond(
+                    tile_ok,
+                    lambda: jax.lax.dynamic_slice(
+                        rows_init, (zero, t0, zero), (B, TILE, M)),
+                    lambda: rows_for_tile(t0))
+            else:
+                rows_tile = rows_for_tile(t0)
         u_tile = jax.lax.dynamic_slice(u, (zero, t0), (B, TILE))
         ts_tile = t0 + jnp.arange(TILE, dtype=jnp.int32)
 
@@ -612,10 +594,13 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
             rows_buf, rows_tile, (zero, t0, zero))
         return k + 1, prev, best, best_t, paths, cost_buf, rows_buf
 
-    k_end, _, best, best_t, paths, cost_buf, rows_buf = jax.lax.while_loop(
-        cond, body,
-        (k0, init_col, jnp.zeros((B,), dt), jnp.full((B,), -1, jnp.int32),
-         jnp.zeros((3,), jnp.int32), cost_buf0, rows_buf0))
+    with jax.named_scope("decide.dp"):
+        k_end, _, best, best_t, paths, cost_buf, rows_buf = \
+            jax.lax.while_loop(
+                cond, body,
+                (k0, init_col, jnp.zeros((B,), dt),
+                 jnp.full((B,), -1, jnp.int32), jnp.zeros((3,), jnp.int32),
+                 cost_buf0, rows_buf0))
     return best_t, best, rows_buf, cost_buf, k0, k_end, paths
 
 
@@ -630,6 +615,7 @@ def _decide_tiled(sd, jd, tabs, rows_init, valid_tiles, T: int, d1: int,
 
 
 @jax.jit
+@jax.named_scope("decide.backtrack")
 def _backtrack(rows_lane: jax.Array, cost_lane: jax.Array, best_t, d_tot,
                t_start):
     """Split recovery for ONE accepted lane, from the decision loop's
@@ -683,6 +669,7 @@ def _backtrack(rows_lane: jax.Array, cost_lane: jax.Array, best_t, d_tot,
 
 
 @functools.partial(jax.jit, static_argnames=("wa",))
+@jax.named_scope("decide.placement")
 def _place_slots(sd, resbw, Wc, Zc, ts, wa: int):
     """Greedy placements for the ACTIVE slots of an accepted schedule.
 
@@ -728,7 +715,9 @@ def _decide_core(sd, jd, *, d1: int, use_pallas: bool):
 
     Returns (best_t i32 (-1 = reject), payoff, total_cost, d_left i32 —
     workload still unassigned after the backtrack, 0 for any sound accept —
-    d_slots (T,) i32, y (T, H) i32, z (T, K) i32).
+    d_slots (T,) i32, y (T, H) i32, z (T, K) i32).  Its four stages run
+    under the device scopes ``decide.rows``, ``decide.dp``,
+    ``decide.backtrack`` and ``decide.placement``.
     """
     g, v, wcaps, scaps, U1, U2, L1, L2 = sd
     resbw, WZ, u, meta = jd
@@ -740,78 +729,85 @@ def _decide_core(sd, jd, *, d1: int, use_pallas: bool):
     M = W.shape[0]
     dt = g.dtype
 
-    # dual prices p = L1 (U1/L1)^(g/c), q = L2 (U2/L2)^(v/c)   (eq. 22, 25)
-    p = L1 * _price_pow(jnp.maximum(U1 / L1, 1.0 + 1e-9)[None, None, :],
-                        g / jnp.maximum(wcaps, 1e-12)[None])
-    q = L2 * _price_pow(jnp.maximum(U2 / L2, 1.0 + 1e-9)[None, None, :],
-                        v / jnp.maximum(scaps, 1e-12)[None])
+    with jax.named_scope("decide.rows"):
+        # dual prices p = L1 (U1/L1)^(g/c), q = L2 (U2/L2)^(v/c) (eq. 22, 25)
+        p = L1 * _price_pow(jnp.maximum(U1 / L1, 1.0 + 1e-9)[None, None, :],
+                            g / jnp.maximum(wcaps, 1e-12)[None])
+        q = L2 * _price_pow(jnp.maximum(U2 / L2, 1.0 + 1e-9)[None, None, :],
+                            v / jnp.maximum(scaps, 1e-12)[None])
 
-    w_order, w_scap, w_scost, w_ccap, w_ccost = _prefix_tables_jnp(
-        p, wcaps[None] - g, wres)
-    s_order, s_scap, s_scost, s_ccap, s_ccost = _prefix_tables_jnp(
-        q, scaps[None] - v, sres)
+        w_order, w_scap, w_scost, w_ccap, w_ccost = _prefix_tables_jnp(
+            p, wcaps[None] - g, wres)
+        s_order, s_scap, s_scost, s_ccap, s_ccost = _prefix_tables_jnp(
+            q, scaps[None] - v, sres)
 
-    # COST_t rows for all (t, d)
-    Wt = jnp.broadcast_to(W.astype(dt)[None, :], (T, M))
-    w_costs = _greedy_cost_jnp(w_ccap, w_ccost, w_scost, Wt)
-    pool = s_ccap[:, -1:]                                        # (T, 1)
-    deploy = jnp.minimum(jnp.minimum(Z, W).astype(dt)[None, :], pool)
-    feas_n = (W <= nchunks)[None, :]
-    feas_ps = deploy * psbw >= Wt * wbw - 1e-9
-    z_costs = _greedy_cost_jnp(s_ccap, s_ccost, s_scost, deploy)
-    rows = jnp.where(feas_n & feas_ps, w_costs + z_costs, jnp.inf)
-    rows = rows.at[:, 0].set(0.0)
-    # slots before arrival carry the DP unchanged: row = [0, inf, ...]
-    ts = jnp.arange(T, dtype=jnp.int32)
-    pre = (ts[:, None] < a) & (jnp.arange(M)[None, :] > 0)
-    rows = jnp.where(pre, jnp.inf, rows)
+        # COST_t rows for all (t, d)
+        Wt = jnp.broadcast_to(W.astype(dt)[None, :], (T, M))
+        w_costs = _greedy_cost_jnp(w_ccap, w_ccost, w_scost, Wt)
+        pool = s_ccap[:, -1:]                                    # (T, 1)
+        deploy = jnp.minimum(jnp.minimum(Z, W).astype(dt)[None, :], pool)
+        feas_n = (W <= nchunks)[None, :]
+        feas_ps = deploy * psbw >= Wt * wbw - 1e-9
+        z_costs = _greedy_cost_jnp(s_ccap, s_ccost, s_scost, deploy)
+        rows = jnp.where(feas_n & feas_ps, w_costs + z_costs, jnp.inf)
+        rows = rows.at[:, 0].set(0.0)
+        # slots before arrival carry the DP unchanged: row = [0, inf, ...]
+        ts = jnp.arange(T, dtype=jnp.int32)
+        pre = (ts[:, None] < a) & (jnp.arange(M)[None, :] > 0)
+        rows = jnp.where(pre, jnp.inf, rows)
 
-    # banded min-plus DP over slots (cost only; splits recovered below)
-    if use_pallas:
-        cost_tab = minplus_sweep(rows, d1 - 1)[0].astype(dt)
-    else:
-        cost_tab = minplus_sweep_cost(rows, d1 - 1)
+    with jax.named_scope("decide.dp"):
+        # banded min-plus DP over slots (cost only; splits recovered below)
+        if use_pallas:
+            cost_tab = minplus_sweep(rows, d1 - 1)[0].astype(dt)
+        else:
+            cost_tab = minplus_sweep_cost(rows, d1 - 1)
 
-    # payoff argmax with the reference tie rule (> best + eps switches)
-    costD = jnp.take(cost_tab, d_tot, axis=1)                    # (T,)
-    payoff_t = jnp.where(jnp.isfinite(costD) & (ts >= a), u - costD, -jnp.inf)
+        # payoff argmax with the reference tie rule (> best + eps switches)
+        costD = jnp.take(cost_tab, d_tot, axis=1)                # (T,)
+        payoff_t = jnp.where(jnp.isfinite(costD) & (ts >= a), u - costD,
+                             -jnp.inf)
 
-    def _pick(carry, x):
-        best, best_t = carry
-        pt, t = x
-        switch = pt > best + _PAY_EPS
-        return (jnp.where(switch, pt, best),
-                jnp.where(switch, t, best_t)), None
+        def _pick(carry, x):
+            best, best_t = carry
+            pt, t = x
+            switch = pt > best + _PAY_EPS
+            return (jnp.where(switch, pt, best),
+                    jnp.where(switch, t, best_t)), None
 
-    (best_payoff, best_t), _ = jax.lax.scan(
-        _pick, (jnp.asarray(0.0, dt), jnp.int32(-1)), (payoff_t, ts))
+        (best_payoff, best_t), _ = jax.lax.scan(
+            _pick, (jnp.asarray(0.0, dt), jnp.int32(-1)), (payoff_t, ts))
 
-    # backtrack from best_t down to arrival, recomputing each slot's split
-    # as argmin_j rows[t, j] + cost_{t-1}[d_rem - j] over the stored table —
-    # the same first-minimum the carried DP argmin would have produced
-    init_row = jnp.full((d1,), jnp.inf, dt).at[0].set(0.0)
-    prev_tab = jnp.concatenate([init_row[None, :], cost_tab[:-1]], axis=0)
-    js = jnp.arange(M)
+    with jax.named_scope("decide.backtrack"):
+        # backtrack from best_t down to arrival, recomputing each slot's
+        # split as argmin_j rows[t, j] + cost_{t-1}[d_rem - j] over the
+        # stored table — the same first-minimum the carried DP argmin
+        # would have produced
+        init_row = jnp.full((d1,), jnp.inf, dt).at[0].set(0.0)
+        prev_tab = jnp.concatenate([init_row[None, :], cost_tab[:-1]],
+                                   axis=0)
+        js = jnp.arange(M)
 
-    def _back(d_rem, x):
-        row, prev, t = x
-        idx = d_rem - js
-        vals = jnp.where(idx >= 0, row + prev[jnp.clip(idx, 0, d1 - 1)],
-                         jnp.inf)
-        d_here = jnp.where(t <= best_t,
-                           jnp.argmin(vals).astype(jnp.int32), 0)
-        return d_rem - d_here, d_here
+        def _back(d_rem, x):
+            row, prev, t = x
+            idx = d_rem - js
+            vals = jnp.where(idx >= 0,
+                             row + prev[jnp.clip(idx, 0, d1 - 1)], jnp.inf)
+            d_here = jnp.where(t <= best_t,
+                               jnp.argmin(vals).astype(jnp.int32), 0)
+            return d_rem - d_here, d_here
 
-    d_left, d_slots = jax.lax.scan(_back, d_tot, (rows, prev_tab, ts),
-                                   reverse=True)
+        d_left, d_slots = jax.lax.scan(_back, d_tot, (rows, prev_tab, ts),
+                                       reverse=True)
 
-    # greedy placements for the chosen per-slot counts
-    W_slots = jnp.take(W, d_slots)
-    Z_slots = jnp.take(Z, d_slots)
-    deploy_slots = jnp.minimum(jnp.minimum(Z_slots, W_slots).astype(dt),
-                               pool[:, 0])
-    y = _greedy_place_jnp(w_order, w_scap, w_ccap, W_slots.astype(dt))
-    z = _greedy_place_jnp(s_order, s_scap, s_ccap, deploy_slots)
+    with jax.named_scope("decide.placement"):
+        # greedy placements for the chosen per-slot counts
+        W_slots = jnp.take(W, d_slots)
+        Z_slots = jnp.take(Z, d_slots)
+        deploy_slots = jnp.minimum(
+            jnp.minimum(Z_slots, W_slots).astype(dt), pool[:, 0])
+        y = _greedy_place_jnp(w_order, w_scap, w_ccap, W_slots.astype(dt))
+        z = _greedy_place_jnp(s_order, s_scap, s_ccap, deploy_slots)
 
     total_cost = jnp.take(costD, jnp.maximum(best_t, 0))
     return best_t, best_payoff, total_cost, d_left, d_slots, y, z
@@ -924,6 +920,7 @@ def _price_tables(g, v, wcaps, scaps, U1, U2, L1, L2):
 
 
 @functools.partial(jax.jit, static_argnames=("T_pad",))
+@jax.named_scope("price.pad")
 def _pad_state(g, v, wcaps, scaps, U1, U2, L1, L2, T_pad: int):
     """Tile-pad the allocation tensors and precompute everything about
     the state the decide launch re-derived per tile: the live-floor
@@ -945,6 +942,7 @@ def _pad_state(g, v, wcaps, scaps, U1, U2, L1, L2, T_pad: int):
 
 
 @functools.partial(jax.jit, static_argnames=("span",))
+@jax.named_scope("price.pad")
 def _pad_patch(g_pad, v_pad, pmin, p_pad, q_pad, g, v, wcaps, scaps,
                U1, U2, L1, L2, t0, span: int):
     """Refresh one dirty slot span of the padded-state cache in place:
@@ -1002,9 +1000,10 @@ def _padded_state(state: PriceState, dtype, T_pad: int):
                 if span > T:
                     break
                 start = min(max(int(s0), 0), T - span)
-                g_pad, v_pad, pmin, p_pad, q_pad = _pad_patch(
-                    g_pad, v_pad, pmin, p_pad, q_pad, g, v, wcaps, scaps,
-                    U1, U2, L1, L2, jnp.int32(start), span)
+                with _obs.dispatch("_pad_patch"):
+                    g_pad, v_pad, pmin, p_pad, q_pad = _pad_patch(
+                        g_pad, v_pad, pmin, p_pad, q_pad, g, v, wcaps,
+                        scaps, U1, U2, L1, L2, jnp.int32(start), span)
             else:
                 hit = (key, (g_pad, v_pad, wcaps, scaps, U1, U2, L1, L2,
                              pmin, p_pad, q_pad))
@@ -1014,8 +1013,9 @@ def _padded_state(state: PriceState, dtype, T_pad: int):
                 return hit[1]
     if _obs.ENABLED:
         _obs.inc("decide.pad_full")
-    g_pad, v_pad, pmin, p_pad, q_pad = _pad_state(
-        g, v, wcaps, scaps, U1, U2, L1, L2, T_pad=T_pad)
+    with _obs.dispatch("_pad_state"):
+        g_pad, v_pad, pmin, p_pad, q_pad = _pad_state(
+            g, v, wcaps, scaps, U1, U2, L1, L2, T_pad=T_pad)
     hit = (key, (g_pad, v_pad, wcaps, scaps, U1, U2, L1, L2, pmin,
                  p_pad, q_pad))
     _pad_cache[state] = hit
@@ -1163,16 +1163,14 @@ def _materialize(pend: _Pending, state: PriceState, sd, dtype
     job, best_t = pend.job, pend.best_t
     if best_t < 0:
         return None
-    profiling = _profiling()
-    if profiling:
-        t_bt = time.perf_counter()
     with _obs.span("decide.backtrack", jid=job.jid):
-        total_cost, d_left, d_slots = jax.device_get(_backtrack(
-            pend.rows_full[pend.lane], pend.cost_full[pend.lane],
-            jnp.int32(best_t), jnp.int32(job.workload),
-            jnp.int32(pend.t_start)))
-    if profiling:
-        _profile_acc["backtrack"] += time.perf_counter() - t_bt
+        with _obs.dispatch("_backtrack"):
+            out = _backtrack(
+                pend.rows_full[pend.lane], pend.cost_full[pend.lane],
+                jnp.int32(best_t), jnp.int32(job.workload),
+                jnp.int32(pend.t_start))
+        with _obs.span("launch.fetch", program="_backtrack"):
+            total_cost, d_left, d_slots = jax.device_get(out)
     pend.cost = float(total_cost)
     # mirrors _extract's backtrack assert: an accepted schedule must place
     # the whole workload (guards e.g. mixed-precision runs)
@@ -1188,25 +1186,26 @@ def _materialize(pend: _Pending, state: PriceState, sd, dtype
         return Schedule(jid=job.jid, workers={}, ps={}, finish=int(best_t),
                         cost=float(pend.cost),
                         payoff=utility - float(pend.cost), utility=utility)
-    wa = _bucket(len(ts_active), floor=8, step=32)
-    ts = np.full(wa, ts_active[-1], np.int32)
-    ts[:len(ts_active)] = ts_active
-    d_act = np.zeros(wa, d_slots.dtype)
-    d_act[:len(ts_active)] = d_slots[ts_active]
-    Wc = pend.W[d_act].astype(np.float64)
-    Zc = pend.Z[d_act].astype(np.float64)
-    Wc[len(ts_active):] = 0.0
-    Zc[len(ts_active):] = 0.0
-    if profiling:
-        t_pl = time.perf_counter()
     with _obs.span("decide.placement", jid=job.jid, slots=len(ts_active)):
-        y, z = jax.device_get(_place_slots(sd, jnp.asarray(
-            np.concatenate([job.worker_res, job.ps_res,
-                            [job.worker_bw, job.ps_bw]]), dtype),
-            jnp.asarray(Wc, dtype), jnp.asarray(Zc, dtype),
-            jnp.asarray(ts), wa))
-    if profiling:
-        _profile_acc["placement"] += time.perf_counter() - t_pl
+        with _obs.span("decide.prep", jid=job.jid):
+            wa = _bucket(len(ts_active), floor=8, step=32)
+            ts = np.full(wa, ts_active[-1], np.int32)
+            ts[:len(ts_active)] = ts_active
+            d_act = np.zeros(wa, d_slots.dtype)
+            d_act[:len(ts_active)] = d_slots[ts_active]
+            Wc = pend.W[d_act].astype(np.float64)
+            Zc = pend.Z[d_act].astype(np.float64)
+            Wc[len(ts_active):] = 0.0
+            Zc[len(ts_active):] = 0.0
+            args = (jnp.asarray(np.concatenate(
+                        [job.worker_res, job.ps_res,
+                         [job.worker_bw, job.ps_bw]]), dtype),
+                    jnp.asarray(Wc, dtype), jnp.asarray(Zc, dtype),
+                    jnp.asarray(ts))
+        with _obs.dispatch("_place_slots"):
+            out = _place_slots(sd, *args, wa)
+        with _obs.span("launch.fetch", program="_place_slots"):
+            y, z = jax.device_get(out)
     H, K = state.cluster.H, state.cluster.K
     workers, ps = {}, {}
     for k, t in enumerate(ts_active):
@@ -1249,30 +1248,6 @@ def _empty_cache(b_pad: int, T_pad: int, n_tiles: int, m_pad: int,
     rows0[:, :, 1:] = np.inf
     return (jnp.asarray(rows0, jnp.dtype(dtype_name)),
             jnp.zeros((b_pad, n_tiles), bool))
-
-
-# per-branch processed-tile totals across decide launches (the fallback
-# counter of the monotone dispatch; see monotone_counters_snapshot)
-_monotone_counters = {"dnc": 0, "plateau": 0, "chain": 0}
-
-# (b_pad, T_pad, m_pad, d1, mono, use_tabs, dtype) tuples already
-# launched this process: a first sighting means XLA is about to compile
-# a new variant, surfaced as a ``jit_cold_compile`` trace event
-_launch_keys_seen: set = set()
-
-
-def monotone_counters_reset() -> None:
-    for k in _monotone_counters:
-        _monotone_counters[k] = 0
-
-
-def monotone_counters_snapshot() -> dict:
-    """Tiles processed per min-plus branch since the last reset: ``dnc``
-    (divide-and-conquer row-minima), ``plateau`` (staircase run
-    compression), ``chain`` (quadratic banded fallback).  All three are
-    bit-identical; the split records how often the monotone paths fired
-    vs fell back."""
-    return dict(_monotone_counters)
 
 
 class _LaneTabs:
@@ -1342,20 +1317,23 @@ def _lane_tables(chunk, caches, state, psd, lanes, b_pad, T, dtype):
                 tabs_l = None
                 break
             start = min(max(int(s0), 0), T - span)
-            tabs_l = _sorted_fill(tabs_l, p_pad, q_pad, g_pad, v_pad,
-                                  wcaps, scaps, resbw, jnp.int32(start),
-                                  span)
+            with _obs.dispatch("_sorted_fill"):
+                tabs_l = _sorted_fill(tabs_l, p_pad, q_pad, g_pad, v_pad,
+                                      wcaps, scaps, resbw,
+                                      jnp.int32(start), span)
         lane_tabs[bi] = tabs_l
     if all(t is None for t in lane_tabs):
         resbw_all = jnp.asarray(np.stack([la[0] for la in lanes]), dtype)
-        full = _sorted_fill_lanes(p_pad, q_pad, g_pad, v_pad, wcaps,
-                                  scaps, resbw_all)
+        with _obs.dispatch("_sorted_fill_lanes"):
+            full = _sorted_fill_lanes(p_pad, q_pad, g_pad, v_pad, wcaps,
+                                      scaps, resbw_all)
         return full, [_LaneTabs(full, bi) for bi in range(b_pad)], True
     for bi in range(b_pad):
         if lane_tabs[bi] is None:
             resbw = jnp.asarray(lanes[bi][0], dtype)
-            one = _sorted_fill_lanes(p_pad, q_pad, g_pad, v_pad, wcaps,
-                                     scaps, resbw[None])
+            with _obs.dispatch("_sorted_fill_lanes"):
+                one = _sorted_fill_lanes(p_pad, q_pad, g_pad, v_pad, wcaps,
+                                         scaps, resbw[None])
             lane_tabs[bi] = _LaneTabs(one, 0)
     if b_pad == 1:
         lt = lane_tabs[0]
@@ -1378,129 +1356,103 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
     T = state.horizon
     T_pad = _pad_tiles(T)
     n_tiles = T_pad // TILE
-    psd = _padded_state(state, dtype, T_pad)
-    sd = psd
+    with _obs.span("decide.prep"):
+        sd = _padded_state(state, dtype, T_pad)
     out: List[_Pending] = []
     for c0 in range(0, len(jobs), _MAX_LANES):
         chunk = jobs[c0:c0 + _MAX_LANES]
         b_pad = _bucket(len(chunk), floor=1, step=_MAX_LANES)
-        lanes, tables = [], []
-        for _, j in chunk:
-            la, wz = _job_arrays_tiled(j, state, T, T_pad, m_pad, dtype)
-            lanes.append(la)
-            tables.append(wz)
-        for _ in range(b_pad - len(chunk)):
-            la, wz = _reject_lane(T, T_pad, m_pad)
-            lanes.append(la)
-            tables.append(wz)
-        jd = _stack_lanes(lanes, dtype)
-        # the no-cache case runs the SAME compiled variant with an
-        # all-invalid (device-cached) empty cache: every distinct
-        # (shape, use_cache) pair is a separate multi-second XLA
-        # compilation, and the cond-per-tile overhead of the cached
-        # variant is microseconds
-        use_cache = caches is not None and any(
-            caches.get(i) is not None for i, _ in chunk)
-        if use_cache:
-            rows0 = np.zeros((b_pad, T_pad, m_pad))
-            rows0[:, :, 1:] = np.inf
-            valid0 = np.zeros((b_pad, n_tiles), bool)
-            rows_list = [None] * b_pad
-            for bi, (i, _) in enumerate(chunk):
-                cache = caches.get(i)
-                if cache is not None and cache.rows is not None:
-                    rows_list[bi] = cache.rows
-                    valid0[bi] = cache.valid
-            base = jnp.asarray(rows0, dtype)
-            stackable = [rows_list[bi] if rows_list[bi] is not None
-                         else base[bi] for bi in range(b_pad)]
-            rows_init = jnp.stack(stackable)
-            valid_tiles = jnp.asarray(valid0)
-            if _obs.ENABLED:
-                _obs.inc("decide.cache_tiles_valid",
-                         int(valid0[:len(chunk)].sum()))
-                _obs.inc("decide.cache_tiles_total", len(chunk) * n_tiles)
-        else:
-            # cache-less launches stay out of the cache_tiles_* counters:
-            # the tracked hit rate measures how much of a RE-SOLVE the
-            # row cache saved, not how often the cache path ran at all
-            rows_init, valid_tiles = _empty_cache(
-                b_pad, T_pad, n_tiles, m_pad, jnp.dtype(dtype).name)
-        profiling = _profiling()
-        if profiling:
-            jax.block_until_ready((psd, jd, rows_init, valid_tiles))
-            t_tabs = time.perf_counter()
-        tabs, lane_tabs, use_tabs = _lane_tables(chunk, caches, state,
-                                                 psd, lanes, b_pad, T,
-                                                 dtype)
-        if profiling:
-            jax.block_until_ready(tabs)
-            t_launch = time.perf_counter()
-            _profile_acc["row_build"] += t_launch - t_tabs
+        with _obs.span("decide.prep", lanes=len(chunk)):
+            lanes, tables = [], []
+            for _, j in chunk:
+                la, wz = _job_arrays_tiled(j, state, T, T_pad, m_pad, dtype)
+                lanes.append(la)
+                tables.append(wz)
+            for _ in range(b_pad - len(chunk)):
+                la, wz = _reject_lane(T, T_pad, m_pad)
+                lanes.append(la)
+                tables.append(wz)
+            jd = _stack_lanes(lanes, dtype)
+            # the no-cache case runs the SAME compiled variant with an
+            # all-invalid (device-cached) empty cache: every distinct
+            # (shape, use_cache) pair is a separate multi-second XLA
+            # compilation, and the cond-per-tile overhead of the cached
+            # variant is microseconds
+            use_cache = caches is not None and any(
+                caches.get(i) is not None for i, _ in chunk)
+            if use_cache:
+                rows0 = np.zeros((b_pad, T_pad, m_pad))
+                rows0[:, :, 1:] = np.inf
+                valid0 = np.zeros((b_pad, n_tiles), bool)
+                rows_list = [None] * b_pad
+                for bi, (i, _) in enumerate(chunk):
+                    cache = caches.get(i)
+                    if cache is not None and cache.rows is not None:
+                        rows_list[bi] = cache.rows
+                        valid0[bi] = cache.valid
+                base = jnp.asarray(rows0, dtype)
+                stackable = [rows_list[bi] if rows_list[bi] is not None
+                             else base[bi] for bi in range(b_pad)]
+                rows_init = jnp.stack(stackable)
+                valid_tiles = jnp.asarray(valid0)
+                if _obs.ENABLED:
+                    _obs.inc("decide.cache_tiles_valid",
+                             int(valid0[:len(chunk)].sum()))
+                    _obs.inc("decide.cache_tiles_total",
+                             len(chunk) * n_tiles)
+            else:
+                # cache-less launches stay out of the cache_tiles_*
+                # counters: the tracked hit rate measures how much of a
+                # RE-SOLVE the row cache saved, not how often the cache
+                # path ran at all
+                rows_init, valid_tiles = _empty_cache(
+                    b_pad, T_pad, n_tiles, m_pad, jnp.dtype(dtype).name)
+            tabs, lane_tabs, use_tabs = _lane_tables(chunk, caches, state,
+                                                     sd, lanes, b_pad, T,
+                                                     dtype)
         mono = 0
         if b_pad == 1 and m_pad <= _mono_band():
             mono = 2 if _mono_dnc() else 1
-        launch_key = (b_pad, T_pad, m_pad, d1, mono, use_tabs,
-                      jnp.dtype(dtype).name)
-        if launch_key not in _launch_keys_seen:
-            _launch_keys_seen.add(launch_key)
-            if _obs.ENABLED:
-                _obs.inc("decide.jit_cold_launches")
-                _obs.event("jit_cold_compile", b_pad=b_pad, T_pad=T_pad,
-                           m_pad=m_pad, d1=d1, mono=mono,
-                           use_tabs=use_tabs)
-        dp_span = _obs.span("decide.dp_sweep", lanes=len(chunk),
-                            T_pad=T_pad, m_pad=m_pad)
-        dp_span.__enter__()
-        best_t, payoff, rows_buf, cost_buf, k0, k_end, paths = \
-            _decide_tiled(sd, jd, tabs, rows_init, valid_tiles, T=T,
-                          d1=d1, use_cache=True, mono=mono,
-                          use_tabs=use_tabs)
-        if profiling:
-            jax.block_until_ready((best_t, rows_buf, cost_buf))
-            total = time.perf_counter() - t_launch
-            # DP-only re-run: every tile served from the row cache the
-            # first launch just refreshed.  The early-exit loop visits
-            # the same tiles (same carries), so the delta is the row
-            # build.  See ``decide_profile_snapshot``.
-            t_dp = time.perf_counter()
-            jax.block_until_ready(_decide_tiled(
-                sd, jd, tabs, rows_buf, jnp.ones_like(valid_tiles), T=T,
-                d1=d1, use_cache=True, mono=mono, use_tabs=use_tabs)[:4])
-            dp_only = time.perf_counter() - t_dp
-            _profile_acc["dp_sweep"] += dp_only
-            _profile_acc["row_build"] += max(total - dp_only, 0.0)
-            _profile_acc["decisions"] += len(chunk)
-        best_t, payoff, k0, k_end, pth = jax.device_get(
-            (best_t, payoff, k0, k_end, paths))
-        k0, k_end = int(k0), int(k_end)
-        dp_span.set(tiles_visited=k_end - k0, n_tiles=n_tiles)
-        dp_span.__exit__(None, None, None)
+        with _obs.span("decide.dp_sweep", lanes=len(chunk), T_pad=T_pad,
+                       m_pad=m_pad) as dp_span:
+            with _obs.dispatch("_decide_tiled"):
+                best_t, payoff, rows_buf, cost_buf, k0, k_end, paths = \
+                    _decide_tiled(sd, jd, tabs, rows_init, valid_tiles,
+                                  T=T, d1=d1, use_cache=True, mono=mono,
+                                  use_tabs=use_tabs)
+            with _obs.span("launch.fetch", program="_decide_tiled"):
+                best_t, payoff, k0, k_end, pth = jax.device_get(
+                    (best_t, payoff, k0, k_end, paths))
+            k0, k_end = int(k0), int(k_end)
+            dp_span.set(tiles_visited=k_end - k0, n_tiles=n_tiles)
         if _obs.ENABLED:
-            _obs.inc("decide.launches")
             _obs.inc("decide.tiles_visited", k_end - k0)
             _obs.inc("decide.tiles_horizon", n_tiles)
             _obs.observe("decide.early_exit_frac",
                          (k_end - k0) / max(n_tiles, 1))
-        _monotone_counters["dnc"] += int(pth[0])
-        _monotone_counters["plateau"] += int(pth[1])
-        _monotone_counters["chain"] += int(pth[2])
-        for bi, (i, job) in enumerate(chunk):
-            valid = np.zeros(n_tiles, bool)
-            if use_cache and caches.get(i) is not None:
-                valid |= caches[i].valid
-            valid[k0:k_end] = True
-            cache = RowCache(rows=rows_buf[bi], valid=valid,
-                             version=state.version, m_pad=m_pad, d1=d1,
-                             tables=lane_tabs[bi],
-                             tables_version=(state.version
-                                             if lane_tabs[bi] is not None
-                                             else -1))
-            out.append(_Pending(
-                job=job, best_t=int(best_t[bi]), payoff=float(payoff[bi]),
-                rows_full=rows_buf, cost_full=cost_buf, lane=bi,
-                t_start=k0 * TILE, W=tables[bi][0], Z=tables[bi][1],
-                cache=cache))
+            # tiles per min-plus branch (bit-identical paths): how often
+            # the monotone fast paths fired against the chain fallback
+            _obs.inc("decide.path_dnc", int(pth[0]))
+            _obs.inc("decide.path_plateau", int(pth[1]))
+            _obs.inc("decide.path_chain", int(pth[2]))
+        # each lane's row cache: the inputs of its later re-solves
+        with _obs.span("decide.prep", lanes=len(chunk)):
+            for bi, (i, job) in enumerate(chunk):
+                valid = np.zeros(n_tiles, bool)
+                if use_cache and caches.get(i) is not None:
+                    valid |= caches[i].valid
+                valid[k0:k_end] = True
+                cache = RowCache(rows=rows_buf[bi], valid=valid,
+                                 version=state.version, m_pad=m_pad, d1=d1,
+                                 tables=lane_tabs[bi],
+                                 tables_version=(state.version
+                                                 if lane_tabs[bi] is not None
+                                                 else -1))
+                out.append(_Pending(
+                    job=job, best_t=int(best_t[bi]),
+                    payoff=float(payoff[bi]), rows_full=rows_buf,
+                    cost_full=cost_buf, lane=bi, t_start=k0 * TILE,
+                    W=tables[bi][0], Z=tables[bi][1], cache=cache))
     return out
 
 
@@ -1564,13 +1516,16 @@ def best_schedule_fused(job: Job, state: PriceState, *,
     with _x64_context(precision):
         dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
         if use_pallas:
-            sd = _state_arrays(state, dtype)
-            jd = _job_arrays(job, T, m_pad, dtype)
-            best_t, _, cost, d_left, d_slots, y, z = _decide_one(
-                sd, jd, d1=d1, use_pallas=True)
+            with _obs.span("decide.prep", jid=job.jid):
+                sd = _state_arrays(state, dtype)
+                jd = _job_arrays(job, T, m_pad, dtype)
+            with _obs.dispatch("_decide_one"):
+                out = _decide_one(sd, jd, d1=d1, use_pallas=True)
+            with _obs.span("launch.fetch", program="_decide_one"):
+                best_t, _, cost, d_left, d_slots, y, z = jax.device_get(out)
             return _schedule_from_outputs(
                 job, state, int(best_t), float(cost), int(d_left),
-                np.asarray(d_slots), np.asarray(y), np.asarray(z))
+                d_slots, y, z)
         caches = {0: row_cache} if row_cache is not None else None
         pend = _decide_jobs([(0, job)], state, dtype, m_pad, d1,
                             caches=caches)[0]
@@ -1580,7 +1535,8 @@ def best_schedule_fused(job: Job, state: PriceState, *,
             row_cache.version = pend.cache.version
             row_cache.tables = pend.cache.tables
             row_cache.tables_version = pend.cache.tables_version
-        sd = _state_arrays(state, dtype)
+        with _obs.span("decide.prep", jid=job.jid):
+            sd = _state_arrays(state, dtype)
         return _materialize(pend, state, sd, dtype)
 
 
@@ -1634,7 +1590,8 @@ def best_schedule_fused_batch(jobs: Sequence[Job], state: PriceState, *,
     out: List[Optional[Schedule]] = [None] * len(jobs)
     with _x64_context(precision):
         dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-        sd = _state_arrays(state, dtype)
+        with _obs.span("decide.prep"):
+            sd = _state_arrays(state, dtype)
         for i, pend in enumerate(pends):
             if pend is not None:
                 out[i] = _materialize(pend, state, sd, dtype)

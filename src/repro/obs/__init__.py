@@ -21,6 +21,12 @@ the duration of the run via :func:`activate`, restoring the previous
 state on exit; :func:`enable` installs a process-global recorder for
 CLI use (``examples/cluster_sim.py --trace out.json``).  See
 ``docs/OBSERVABILITY.md`` for the span/metric catalog.
+
+Where JAX is installed, a recorder's spans are also opened as
+``jax.profiler.TraceAnnotation``s (a profiled run shows them on the
+device trace's clock), and every backend compile while a recorder is
+installed lands as a ``jax.compile`` instant under the open span.  JAX
+is imported on the first ``Obs()``, never by importing this package.
 """
 from __future__ import annotations
 
@@ -31,8 +37,8 @@ from .metrics import DEFAULT_BUCKETS, Histogram, Registry
 from .trace import NULL_SPAN, NullSpan, Span, Tracer
 
 __all__ = [
-    "ENABLED", "Obs", "activate", "current", "disable", "enable",
-    "event", "inc", "observe", "set_gauge", "span",
+    "ENABLED", "Obs", "activate", "current", "disable", "dispatch",
+    "enable", "event", "inc", "observe", "set_gauge", "span",
     "DEFAULT_BUCKETS", "Histogram", "Registry",
     "NULL_SPAN", "NullSpan", "Span", "Tracer",
 ]
@@ -43,12 +49,39 @@ ENABLED: bool = False
 
 _CURRENT: Optional["Obs"] = None
 
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_jax_hooked = False
+_annotation: Any = None
+
+
+def _hook_jax() -> Any:
+    """``jax.profiler.TraceAnnotation`` (None without JAX); on first use
+    also registers the one compile listener this package keeps."""
+    global _jax_hooked, _annotation
+    if not _jax_hooked:
+        _jax_hooked = True
+        try:
+            import jax.monitoring
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            return None
+        _annotation = TraceAnnotation
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    return _annotation
+
+
+def _on_compile(event: str, seconds: float, **_: Any) -> None:
+    ob = _CURRENT
+    if ob is not None and event == COMPILE_EVENT:
+        ob.metrics.inc("jax.compiles")
+        ob.tracer.instant("jax.compile", seconds=seconds)
+
 
 class Obs:
     """One tracer + one metrics registry, recorded together."""
 
     def __init__(self, capacity: int = 65536):
-        self.tracer = Tracer(capacity=capacity)
+        self.tracer = Tracer(capacity=capacity, annotate=_hook_jax())
         self.metrics = Registry()
 
     def export_chrome(self, path: str) -> int:
@@ -106,6 +139,17 @@ def span(name: str, **attrs: Any) -> Union[Span, NullSpan]:
     if ob is None:
         return NULL_SPAN
     return ob.tracer.span(name, **attrs)
+
+
+def dispatch(program: str) -> Union[Span, NullSpan]:
+    """``launch.dispatch`` span around a call that enqueues device work
+    (``program``: the jitted function's name); counts the launch in
+    ``decide.launches``."""
+    ob = _CURRENT
+    if ob is None:
+        return NULL_SPAN
+    ob.metrics.inc("decide.launches")
+    return ob.tracer.span("launch.dispatch", program=program)
 
 
 def event(name: str, **attrs: Any) -> None:

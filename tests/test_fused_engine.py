@@ -230,17 +230,21 @@ def test_backend_trajectories_identical_paper_scale(seed):
     — reproduces the ref oracle's whole trajectory BIT-identically:
     accept set, completion slots, and total utility (exact float
     equality, not approx) — with the monotone min-plus dispatch active
-    (the path counters must show its fast paths firing, i.e. the chain
-    fallback stays below 100% on every instance)."""
-    from repro.core.schedule_jax import (monotone_counters_reset,
-                                         monotone_counters_snapshot)
+    (the obs path counters ``decide.path_*`` must show its fast paths
+    firing, i.e. the chain fallback stays below 100% on every
+    instance)."""
+    from repro import obs as obslib
     from repro.sim import simulate
     cluster = make_cluster(T=100, H=50, K=50)
     jobs = make_jobs(200, T=100, seed=seed, small=True)
     a = simulate(cluster, jobs, scheduler="oasis", impl="ref", quantum=0)
-    monotone_counters_reset()
-    b = simulate(cluster, jobs, scheduler="oasis", impl="jax", quantum=0)
-    snap = monotone_counters_snapshot()
+    ob = obslib.Obs()
+    with obslib.activate(ob):
+        b = simulate(cluster, jobs, scheduler="oasis", impl="jax",
+                     quantum=0)
+    c = ob.metrics.snapshot()["counters"]
+    snap = {k: c.get(f"decide.path_{k}", 0)
+            for k in ("dnc", "plateau", "chain")}
     assert sum(snap.values()) > 0, "monotone dispatch inactive"
     assert snap["chain"] < sum(snap.values()), f"fallback at 100%: {snap}"
     assert a.completion == b.completion

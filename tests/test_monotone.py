@@ -236,18 +236,21 @@ def test_monotone_matches_chain_randomized(seed):
 
 def test_engine_monotone_fallback_below_100_percent():
     """Paper-scale instance with the monotone dispatch active: the
-    per-launch path counters must show the plateau path actually firing
+    obs path counters (``decide.path_*``) must show the plateau path firing
     (fallback < 100%) AND the trajectory must equal the chain-only
     engine (REPRO_MONOTONE_BAND=0) exactly."""
     import os
-    from repro.core.schedule_jax import (monotone_counters_reset,
-                                         monotone_counters_snapshot)
+    from repro import obs as obslib
     from repro.sim import make_cluster, make_jobs, simulate
     cluster = make_cluster(T=100, H=50, K=50)
     jobs = make_jobs(200, T=100, seed=0, small=True)
-    monotone_counters_reset()
-    a = simulate(cluster, jobs, scheduler="oasis", impl="jax", quantum=0)
-    snap = monotone_counters_snapshot()
+    ob = obslib.Obs()
+    with obslib.activate(ob):
+        a = simulate(cluster, jobs, scheduler="oasis", impl="jax",
+                     quantum=0)
+    c = ob.metrics.snapshot()["counters"]
+    snap = {k: c.get(f"decide.path_{k}", 0)
+            for k in ("dnc", "plateau", "chain")}
     total = sum(snap.values())
     assert total > 0, "monotone dispatch never active at paper scale"
     assert snap["chain"] < total, f"fallback at 100%: {snap}"

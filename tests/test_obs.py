@@ -14,6 +14,7 @@ Pins the four contracts the subsystem makes:
   module-global disabled state on return.
 """
 import json
+import re
 import time
 
 import numpy as np
@@ -82,7 +83,7 @@ def test_chrome_export_schema(tmp_path):
     with tr.span("decide", jid=7, impl="jax"):
         with tr.span("dp_sweep", arr=np.arange(3)):   # non-scalar arg
             pass
-    tr.instant("jit_cold_compile", T_pad=128)
+    tr.instant("jax.compile", seconds=0.5)
     path = tmp_path / "trace.json"
     n = tr.export_chrome(str(path), metrics={"counters": {"x": 1}})
     assert n == 3
@@ -334,3 +335,213 @@ def test_obs_export_embeds_metrics(tmp_path):
     doc = json.loads(path.read_text())
     assert len(doc["traceEvents"]) == n > 0
     assert doc["metrics"]["counters"]["decide.decisions"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# launch boundary, parent ids, profiler mirror, compile instants
+# ---------------------------------------------------------------------------
+
+# spans that belong to one job carry its id
+_PER_JOB = ("decide", "decide.resolve", "decide.row_cache_sync",
+            "decide.backtrack", "decide.placement", "price.commit")
+
+
+def test_jax_stream_run_records_the_launch_boundary():
+    cluster, jobs = _instance(T=48, HK=6, n=24)
+    jobs = sorted(jobs, key=lambda j: j.arrival)
+    r0 = engine.run_stream(cluster, iter(jobs), scheduler="oasis",
+                           impl="jax", window=48)
+    ob = obslib.Obs()
+    r1 = engine.run_stream(cluster, iter(jobs), scheduler="oasis",
+                           impl="jax", window=48, obs=ob)
+    assert r0.summary() == r1.summary()
+    assert r0.completion == r1.completion
+    evs = list(ob.tracer.events())
+    names = {e["name"] for e in evs}
+    assert {"decide.prep", "launch.dispatch", "launch.fetch",
+            "decide_burst", "decide.dp_sweep", "price.commit"} <= names
+    by_id = {e["id"]: e for e in evs}
+    assert len(by_id) == len(evs)
+    for e in evs:
+        p = e["parent"]
+        if p is None:
+            assert e["depth"] == 0
+            continue
+        par = by_id[p]
+        assert e["depth"] == par["depth"] + 1
+        end = e["ts_us"] + (e["dur_us"] or 0.0)
+        assert par["ts_us"] <= e["ts_us"]
+        assert end <= par["ts_us"] + par["dur_us"] + 1e-3
+    for e in evs:
+        if e["name"] in _PER_JOB:
+            assert "jid" in e["args"], e
+        if e["name"] == "arrival_burst":
+            assert "t" in e["args"]
+        if e["name"] in ("launch.dispatch", "launch.fetch"):
+            assert e["args"]["program"]
+    dispatch = [e for e in evs if e["name"] == "launch.dispatch"]
+    c = ob.metrics.snapshot()["counters"]
+    assert c["decide.launches"] == len(dispatch)
+    programs = {e["args"]["program"] for e in dispatch}
+    assert {"_decide_tiled", "_backtrack", "_place_slots",
+            "_window_set"} <= programs
+    # every blocking read-back of a decide launch sits in a fetch span
+    fetched = {e["args"]["program"] for e in evs
+               if e["name"] == "launch.fetch"}
+    assert {"_decide_tiled", "_backtrack", "_place_slots"} <= fetched
+
+
+def test_single_arrival_kernel_path_records_its_launch():
+    """The TPU's single-arrival path (``use_pallas=True``; interpret mode
+    here): prep, one ``_decide_one`` launch and one fetch."""
+    from repro.core.pricing import PriceState, price_params_from_jobs
+    from repro.core.schedule_jax import best_schedule_fused
+    cluster = make_cluster(T=10, H=3, K=3)
+    jobs = make_jobs(2, T=10, seed=1, small=True)
+    state = PriceState(cluster, price_params_from_jobs(jobs, cluster))
+    want = best_schedule_fused(jobs[0], state, use_pallas=True)
+    ob = obslib.Obs()
+    with obslib.activate(ob):
+        got = best_schedule_fused(jobs[0], state, use_pallas=True)
+    assert (got is None) == (want is None)
+    if want is not None:
+        assert got.finish == want.finish and got.workers.keys() == \
+            want.workers.keys()
+    evs = list(ob.tracer.events())
+    prog = [(e["name"], e["args"]["program"]) for e in evs
+            if e["name"] in ("launch.dispatch", "launch.fetch")]
+    assert prog == [("launch.dispatch", "_decide_one"),
+                    ("launch.fetch", "_decide_one")]
+    assert [e["args"]["jid"] for e in evs
+            if e["name"] == "decide.prep"] == [jobs[0].jid]
+    assert ob.metrics.counter("decide.launches") == 1
+
+
+def test_disabled_jax_run_emits_nothing():
+    cluster, jobs = _instance()
+    ob = obslib.Obs()
+    engine.run(cluster, jobs, scheduler="oasis", impl="jax")
+    assert len(ob.tracer) == 0
+    assert ob.metrics.snapshot()["counters"] == {}
+
+
+def test_instants_and_spans_carry_ids():
+    tr = Tracer()
+    with tr.span("a") as a:
+        tr.instant("mark", k=1)
+        with tr.span("b"):
+            pass
+    mark, b, a_ev = list(tr.events())
+    assert a_ev["parent"] is None and a_ev["id"] == a._id
+    assert mark["parent"] == a_ev["id"] and b["parent"] == a_ev["id"]
+    assert len({mark["id"], b["id"], a_ev["id"]}) == 3
+    (ev,) = [e for e in tr.chrome_events() if e["name"] == "b"]
+    assert ev["args"]["parent_id"] == a_ev["id"]
+
+
+def test_spans_open_trace_annotations_only_under_a_recorder():
+    opened = []
+
+    class Note:
+        def __init__(self, name, **kw):
+            opened.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    tr = Tracer(annotate=Note)
+    with tr.span("launch.dispatch", program="_decide_tiled", arr=[1]):
+        pass
+    assert opened == [("launch.dispatch",
+                       {"program": "_decide_tiled", "arr": "[1]"})]
+    assert obslib.span("x") is NULL_SPAN
+    assert obslib.dispatch("_decide_tiled") is NULL_SPAN
+
+
+def test_fresh_compile_lands_under_the_span_that_triggered_it():
+    import jax
+    k = float(time.perf_counter_ns() % 1000003)     # a program not seen
+    f = jax.jit(lambda x: x * k + 1.0)
+    x = np.arange(7, dtype=np.float32)
+    f(x + 1)                       # compiled with no recorder: not counted
+    g = jax.jit(lambda x: x * k - 1.0)
+    ob = obslib.Obs()
+    with obslib.activate(ob):
+        with obslib.span("outer") as outer:
+            g(x)
+        g(x)                       # cached: no compile
+        f(x)
+    comp = [e for e in ob.tracer.events() if e["name"] == "jax.compile"]
+    assert len(comp) == 1
+    assert comp[0]["parent"] == outer._id
+    assert comp[0]["args"]["seconds"] > 0
+    assert ob.metrics.counter("jax.compiles") == 1
+
+
+def _scope_cases():
+    """Each decide program at a tiny shape, with the scopes it holds."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import schedule_jax as sj
+    from repro.core.types import R
+    T, H, K, M, D1, B, WA = 64, 3, 3, 64, 128, 2, 8
+    f32, i32 = jnp.float32, jnp.int32
+
+    def s(*shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    state = (s(T, H, R), s(T, K, R), s(H, R), s(K, R), s(R), s(R), s(), s())
+    padded = state + (s(T, R), s(T, H, R), s(T, K, R))
+    tiled_jd = (s(B, 2 * R + 2), s(B, 2, M, dtype=i32), s(B, T), s(B, T),
+                s(B, 4, dtype=i32), s(B))
+    one_jd = (s(2 * R + 2), s(2, M, dtype=i32), s(T), s(3, dtype=i32))
+    return {
+        "_decide_tiled": (lambda: sj._decide_tiled.lower(
+            padded, tiled_jd, (s(1, 1, 1),) * 6, s(B, T, M),
+            s(B, T // 64, dtype=jnp.bool_), T=T, d1=D1, use_cache=True,
+            mono=0, use_tabs=False), ("decide.rows", "decide.dp")),
+        "_decide_one": (lambda: sj._decide_one.lower(
+            state, one_jd, d1=D1, use_pallas=False),
+            ("decide.rows", "decide.dp", "decide.backtrack",
+             "decide.placement")),
+        "_backtrack": (lambda: sj._backtrack.lower(
+            s(T, M), s(T, D1), s(dtype=i32), s(dtype=i32), s(dtype=i32)),
+            ("decide.backtrack",)),
+        "_place_slots": (lambda: sj._place_slots.lower(
+            state, s(2 * R + 2), s(WA), s(WA), s(WA, dtype=i32), wa=WA),
+            ("decide.placement",)),
+        "_pad_state": (lambda: sj._pad_state.lower(*state, T_pad=T),
+                       ("price.pad",)),
+        "_sorted_fill_lanes": (lambda: sj._sorted_fill_lanes.lower(
+            s(T, H, R), s(T, K, R), s(T, H, R), s(T, K, R), s(H, R),
+            s(K, R), s(B, 2 * R + 2)), ("decide.rows",)),
+    }
+
+
+@pytest.mark.parametrize("program", ["_decide_tiled", "_decide_one",
+                                     "_backtrack", "_place_slots",
+                                     "_pad_state", "_sorted_fill_lanes"])
+def test_decide_programs_carry_their_device_scopes(program):
+    lower, scopes = _scope_cases()[program]
+    text = lower().compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes:
+        assert any(f"jit({program})/" in p and f"/{scope}/" in p + "/"
+                   for p in paths), (program, scope)
+    # no scope name may match the sweep kernel's trace pattern
+    assert not any("minplus" in s for s in scopes)
+
+
+def test_cached_programs_are_keyed_on_their_scopes():
+    """A program loaded from the persistent compilation cache keeps the
+    stage scopes of the source that compiled it, so the engine keys the
+    cache on metadata: a program compiled before the scopes existed
+    cannot stand in for one that has them."""
+    import jax
+
+    import repro.core.schedule_jax  # noqa: F401
+
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
