@@ -153,6 +153,40 @@ def _price_pow(ratio: jax.Array, x: jax.Array) -> jax.Array:
     return jnp.exp(x * jnp.log(ratio))
 
 
+def _sort_gather(unit: jax.Array, cap: jax.Array, with_order: bool):
+    """Servers cheapest-first along the last axis as an ``argsort`` and
+    two gathers: ``(scost, scap[, order])``.  The XLA CPU form."""
+    order = jnp.argsort(unit, axis=-1, stable=True)
+    out = (jnp.take_along_axis(unit, order, axis=-1),
+           jnp.take_along_axis(cap, order, axis=-1))
+    return out + (order,) if with_order else out
+
+
+def _sort_carry(unit: jax.Array, cap: jax.Array, with_order: bool):
+    """``_sort_gather``'s values from one stable sort keyed on ``unit``
+    that carries ``cap`` (and, ``with_order``, the server index) beside
+    it, with no gather after it.  ``jnp.argsort`` is that sort with the
+    index alone, so the order, ties included, is the same.  The TPU
+    form."""
+    ops = (unit, cap)
+    if with_order:
+        ops += (jax.lax.broadcasted_iota(
+            jax.dtypes.canonicalize_dtype(jnp.int_), unit.shape,
+            unit.ndim - 1),)
+    return jax.lax.sort(ops, dimension=unit.ndim - 1, is_stable=True,
+                        num_keys=1)
+
+
+def _sort_servers(unit: jax.Array, cap: jax.Array, with_order: bool):
+    """``(scost, scap[, order])``: per-slot unit costs and capacities in
+    cheapest-first server order, ties kept in server order.  Chosen when
+    the program is lowered: a data-dependent gather is the slowest thing
+    a TPU does over a 100-wide server axis; XLA CPU gathers cheaply."""
+    return jax.lax.platform_dependent(
+        unit, cap, tpu=functools.partial(_sort_carry, with_order=with_order),
+        default=functools.partial(_sort_gather, with_order=with_order))
+
+
 def _prefix_tables_jnp(prices: jax.Array, headroom: jax.Array,
                        demand: jax.Array):
     """Per-slot sorted unit costs + prefix sums (whole-array, all slots).
@@ -164,30 +198,68 @@ def _prefix_tables_jnp(prices: jax.Array, headroom: jax.Array,
                       jnp.floor(headroom / safe[None, None, :] + 1e-9),
                       _BIG_CAP)
     cap = jnp.clip(jnp.min(per_r, axis=2), 0.0, _BIG_CAP)        # (T, S)
-    order = jnp.argsort(unit, axis=1, stable=True)
-    scost = jnp.take_along_axis(unit, order, axis=1)
-    scap = jnp.take_along_axis(cap, order, axis=1)
+    scost, scap, order = _sort_servers(unit, cap, with_order=True)
     ccap = jnp.cumsum(scap, axis=1)
     ccost = jnp.cumsum(scap * scost, axis=1)
     return order, scap, scost, ccap, ccost
 
 
-def _greedy_cost_jnp(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
-                     counts: jax.Array) -> jax.Array:
-    """Greedy (cheapest-first) deployment cost for ``counts`` (T, M) at every
-    slot, from (T, S) prefix tables.  +inf where counts exceed capacity."""
-    S = ccap.shape[1]
-    # first prefix covering each count (== np.searchsorted side="left";
-    # binary search, not the quadratic (T, S, M) comparison tensor)
-    idx = jax.vmap(
-        functools.partial(jnp.searchsorted, side="left"))(ccap, counts)
-    zcol = jnp.zeros((ccap.shape[0], 1), ccap.dtype)
-    prev_cap = jnp.take_along_axis(jnp.concatenate([zcol, ccap], 1), idx, 1)
-    prev_cost = jnp.take_along_axis(jnp.concatenate([zcol, ccost], 1), idx, 1)
-    marg = jnp.take_along_axis(scost, jnp.minimum(idx, S - 1), 1)
+def _greedy_cost_search(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
+                        counts: jax.Array) -> jax.Array:
+    """``_greedy_cost`` by binary search and gathers (the XLA CPU form).
+
+    On XLA CPU the dense ``(..., M, S)`` comparison of
+    ``_greedy_cost_dense`` was ~10x the cost of everything else here, and
+    ``searchsorted`` returns exactly its ``(ccap < counts).sum(-1)`` on
+    the nondecreasing ``ccap``."""
+    S = ccap.shape[-1]
+    search = functools.partial(jnp.searchsorted, side="left")
+    for _ in range(ccap.ndim - 1):
+        search = jax.vmap(search)
+    idx = search(ccap, counts)
+    zcol = jnp.zeros(ccap.shape[:-1] + (1,), ccap.dtype)
+    prev_cap = jnp.take_along_axis(
+        jnp.concatenate([zcol, ccap], -1), idx, -1)
+    prev_cost = jnp.take_along_axis(
+        jnp.concatenate([zcol, ccost], -1), idx, -1)
+    marg = jnp.take_along_axis(scost, jnp.minimum(idx, S - 1), -1)
     vals = prev_cost + (counts - prev_cap) * marg
     return jnp.where(counts == 0, 0.0,
-                     jnp.where(counts <= ccap[:, -1:], vals, jnp.inf))
+                     jnp.where(counts <= ccap[..., -1:], vals, jnp.inf))
+
+
+def _greedy_cost_dense(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
+                       counts: jax.Array) -> jax.Array:
+    """``_greedy_cost`` as dense masked reductions over the server axis
+    (the TPU form): the covering prefix is a compare-and-count, and each
+    table is read by a one-hot sum, one value plus zeros, so every number
+    equals ``_greedy_cost_search``'s bit for bit.  A one-hot read does not
+    lean on ``ccost`` being monotone, which a cumsum's summation tree
+    does not promise."""
+    S = ccap.shape[-1]
+    s = jnp.arange(S)
+    idx = (ccap[..., None, :] < counts[..., :, None]).sum(-1)
+
+    def pick(table, i):
+        return jnp.where(s == i[..., None], table[..., None, :], 0.0).sum(-1)
+
+    prev_cap = pick(ccap, idx - 1)
+    prev_cost = pick(ccost, idx - 1)
+    marg = pick(scost, jnp.minimum(idx, S - 1))
+    vals = prev_cost + (counts - prev_cap) * marg
+    return jnp.where(counts == 0, 0.0,
+                     jnp.where(counts <= ccap[..., -1:], vals, jnp.inf))
+
+
+def _greedy_cost(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
+                 counts: jax.Array) -> jax.Array:
+    """Greedy (cheapest-first) deployment cost for ``counts`` (..., M) at
+    every slot, from (..., S) prefix tables.  +inf where counts exceed
+    capacity.  The TPU lowers the dense form (a gather loop is what costs
+    there), every other platform the binary search."""
+    return jax.lax.platform_dependent(ccap, ccost, scost, counts,
+                                      tpu=_greedy_cost_dense,
+                                      default=_greedy_cost_search)
 
 
 def _greedy_place_jnp(order: jax.Array, scap: jax.Array, ccap: jax.Array,
@@ -213,7 +285,9 @@ def _prefix_tables_b(prices: jax.Array, headroom: jax.Array,
     prices/headroom: (TILE, S, R) shared across lanes; demand: (B, R) per
     lane.  Returns (scost, ccap, ccost), each (B, TILE, S) — the greedy
     cost tables only (placement order is extracted by ``_place_slots``,
-    never in the decision loop)."""
+    never in the decision loop).  On the TPU the sort carries each
+    server's capacity beside its unit cost and no index
+    (``_sort_servers``)."""
     unit = (prices[None] * demand[:, None, None, :]).sum(axis=3)
     safe = jnp.where(demand > 0, demand, 1.0)
     per_r = jnp.where(demand[:, None, None, :] > 0,
@@ -221,33 +295,10 @@ def _prefix_tables_b(prices: jax.Array, headroom: jax.Array,
                                 + 1e-9),
                       _BIG_CAP)
     cap = jnp.clip(jnp.min(per_r, axis=3), 0.0, _BIG_CAP)
-    order = jnp.argsort(unit, axis=2, stable=True)
-    scost = jnp.take_along_axis(unit, order, axis=2)
-    scap = jnp.take_along_axis(cap, order, axis=2)
+    scost, scap = _sort_servers(unit, cap, with_order=False)
     ccap = jnp.cumsum(scap, axis=2)
     ccost = jnp.cumsum(scap * scost, axis=2)
     return scost, ccap, ccost
-
-
-def _greedy_cost_b(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
-                   counts: jax.Array) -> jax.Array:
-    """Lane-batched greedy cost: (B, TILE, S) tables, (B, TILE, M) counts."""
-    S = ccap.shape[2]
-    # first prefix covering each count.  ``searchsorted`` (binary search)
-    # returns exactly ``(ccap < counts).sum(axis=2)`` — ``ccap`` is a
-    # nondecreasing cumsum — but skips materializing the (B, TILE, S, M)
-    # comparison tensor, which was ~10x the cost of everything else here.
-    idx = jax.vmap(jax.vmap(
-        functools.partial(jnp.searchsorted, side="left")))(ccap, counts)
-    zcol = jnp.zeros(ccap.shape[:2] + (1,), ccap.dtype)
-    prev_cap = jnp.take_along_axis(
-        jnp.concatenate([zcol, ccap], -1), idx, -1)
-    prev_cost = jnp.take_along_axis(
-        jnp.concatenate([zcol, ccost], -1), idx, -1)
-    marg = jnp.take_along_axis(scost, jnp.minimum(idx, S - 1), -1)
-    vals = prev_cost + (counts - prev_cap) * marg
-    return jnp.where(counts == 0, 0.0,
-                     jnp.where(counts <= ccap[..., -1:], vals, jnp.inf))
 
 
 # ---------------------------------------------------------------------------
@@ -261,10 +312,10 @@ def _sorted_fill_lanes(p, q, g, v, wcaps, scaps, resbw):
     (B, T_pad, H|K) — ``(w_scost, w_ccap, w_ccost, s_scost, s_ccap,
     s_ccost)``.
 
-    ``_prefix_tables_b``'s ops (reduce over R, argsort + cumsum along
+    ``_prefix_tables_b``'s ops (reduce over R, sort + cumsum along
     the trailing server axis) touch each slot independently, so TILE
     slices of these tables are bit-identical to the per-tile tables the
-    decide loop used to build inline — and the per-tile argsorts leave
+    decide loop used to build inline — and the per-tile sorts leave
     the decide launch entirely."""
     wres, sres = resbw[:, :R], resbw[:, R:2 * R]
     w = _prefix_tables_b(p, wcaps[None] - g, wres)
@@ -348,11 +399,11 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         cheapest feasible slots is multiplied in on device).
     tabs: per-job sorted-order/cumsum tables from ``_sorted_fill_lanes``
         — 6 arrays (B, T_pad, H|K) when ``use_tabs``; the decide loop
-        then only slices them, so it runs no prices and no argsorts at
+        then only slices them, so it runs no prices and no sorts at
         all.  When ``use_tabs`` is False (the common first-decision
         path), tabs are (1, 1, 1) dummies and the loop builds each
         visited tile's tables inline from the cached price tables —
-        argsorts only on visited tiles, which the early exit keeps far
+        sorts only on visited tiles, which the early exit keeps far
         below T_pad.
     rows_init/valid_tiles: ``use_cache`` row cache — (B, T_pad, M) rows at
         the current prices plus a (B, n_tiles) tile-validity mask; a tile
@@ -405,11 +456,11 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         """COST_t rows for slots [t0, t0+TILE), all lanes: (B, TILE, M).
 
         ``use_tabs``: assembled from the cached sorted tables (greedy
-        prefix lookups only — the prices and argsorts happened in the
+        prefix lookups only — the prices and sorts happened in the
         table build).  Otherwise the tile's prefix tables are built here
         from slices of the version-cached price tables, with the SAME
         ``_prefix_tables_b`` formulas — the two modes are bit-identical
-        (argsort + cumsum touch each slot independently)."""
+        (sort + cumsum touch each slot independently)."""
         zero = jnp.zeros_like(t0)
         if use_tabs:
             w_scost = jax.lax.dynamic_slice(
@@ -439,11 +490,11 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
             s_scost, s_ccap, s_ccost = _prefix_tables_b(
                 q_t, scaps[None] - v_t, sres)
         Wt = jnp.broadcast_to(Wf[:, None, :], (B, TILE, M))
-        w_costs = _greedy_cost_b(w_ccap, w_ccost, w_scost, Wt)
+        w_costs = _greedy_cost(w_ccap, w_ccost, w_scost, Wt)
         pool = s_ccap[..., -1:]                                  # (B, TILE, 1)
         deploy = jnp.minimum(deploy_target[:, None, :], pool)
         feas_ps = deploy * psbw[:, None, None] >= Wt * wbw[:, None, None] - 1e-9
-        z_costs = _greedy_cost_b(s_ccap, s_ccost, s_scost, deploy)
+        z_costs = _greedy_cost(s_ccap, s_ccost, s_scost, deploy)
         rows = jnp.where(feas_n & feas_ps, w_costs + z_costs, jnp.inf)
         rows = rows.at[:, :, 0].set(0.0)
         # pre-arrival and beyond-horizon slots carry the DP unchanged
@@ -743,12 +794,12 @@ def _decide_core(sd, jd, *, d1: int, use_pallas: bool):
 
         # COST_t rows for all (t, d)
         Wt = jnp.broadcast_to(W.astype(dt)[None, :], (T, M))
-        w_costs = _greedy_cost_jnp(w_ccap, w_ccost, w_scost, Wt)
+        w_costs = _greedy_cost(w_ccap, w_ccost, w_scost, Wt)
         pool = s_ccap[:, -1:]                                    # (T, 1)
         deploy = jnp.minimum(jnp.minimum(Z, W).astype(dt)[None, :], pool)
         feas_n = (W <= nchunks)[None, :]
         feas_ps = deploy * psbw >= Wt * wbw - 1e-9
-        z_costs = _greedy_cost_jnp(s_ccap, s_ccost, s_scost, deploy)
+        z_costs = _greedy_cost(s_ccap, s_ccost, s_scost, deploy)
         rows = jnp.where(feas_n & feas_ps, w_costs + z_costs, jnp.inf)
         rows = rows.at[:, 0].set(0.0)
         # slots before arrival carry the DP unchanged: row = [0, inf, ...]
@@ -1348,6 +1399,15 @@ def _lane_tables(chunk, caches, state, psd, lanes, b_pad, T, dtype):
     return tabs, lane_tabs, True
 
 
+def _rows_lookup_counter(x: jax.Array) -> str:
+    """The obs counter of the COST-row tiles a launch built: the launch
+    on ``x``'s device lowered ``_greedy_cost``'s dense form on a TPU and
+    its binary search elsewhere."""
+    if next(iter(x.devices())).platform == "tpu":
+        return "decide.rows_dense_tiles"
+    return "decide.rows_search_tiles"
+
+
 def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
                  m_pad: int, d1: int,
                  caches: Optional[dict] = None) -> List[_Pending]:
@@ -1428,6 +1488,11 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
         if _obs.ENABLED:
             _obs.inc("decide.tiles_visited", k_end - k0)
             _obs.inc("decide.tiles_horizon", n_tiles)
+            # a visited tile's rows are served from the row cache only
+            # where every lane holds it (``_decide_tiled_core``)
+            served = (int(valid0[:, k0:k_end].all(axis=0).sum())
+                      if use_cache else 0)
+            _obs.inc(_rows_lookup_counter(rows_buf), k_end - k0 - served)
             _obs.observe("decide.early_exit_frac",
                          (k_end - k0) / max(n_tiles, 1))
             # tiles per min-plus branch (bit-identical paths): how often
@@ -1523,6 +1588,8 @@ def best_schedule_fused(job: Job, state: PriceState, *,
                 out = _decide_one(sd, jd, d1=d1, use_pallas=True)
             with _obs.span("launch.fetch", program="_decide_one"):
                 best_t, _, cost, d_left, d_slots, y, z = jax.device_get(out)
+            if _obs.ENABLED:
+                _obs.inc(_rows_lookup_counter(out[0]), _pad_tiles(T) // TILE)
             return _schedule_from_outputs(
                 job, state, int(best_t), float(cost), int(d_left),
                 d_slots, y, z)

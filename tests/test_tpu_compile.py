@@ -9,6 +9,7 @@ fixture, never at import, so every worker collects the same tests and only
 the one that runs them loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,24 +66,19 @@ def test_single_slot_kernel_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_legacy_decide_launch_holds_the_kernel(one_chip):
-    """The single-arrival launch on TPU (``use_pallas=True``) carries the
-    Mosaic kernel, not the interpreter or a jnp stand-in."""
+def _decide_one_launch(sh):
+    """The single-arrival launch on TPU (``use_pallas=True``), compiled."""
     from repro.core.schedule_jax import _decide_one
     m_pad = 128
-    jd = (_spec(one_chip, (2 * R + 2,)),
-          _spec(one_chip, (2, m_pad), jnp.int32),
-          _spec(one_chip, (T,)), _spec(one_chip, (3,), jnp.int32))
-    c = _decide_one.lower(_state(one_chip, T), jd, d1=D1,
-                          use_pallas=True).compile()
-    assert "tpu_custom_call" in c.as_text()
+    jd = (_spec(sh, (2 * R + 2,)), _spec(sh, (2, m_pad), jnp.int32),
+          _spec(sh, (T,)), _spec(sh, (3,), jnp.int32))
+    return _decide_one.lower(_state(sh, T), jd, d1=D1,
+                             use_pallas=True).compile()
 
 
-@pytest.mark.parametrize("m_pad", [64, 128])
-def test_tiled_decide_compiles_with_eight_lanes(one_chip, m_pad):
-    """The burst launch at the TPU lane count."""
+def _tiled_launch(sh, m_pad):
+    """The burst launch at the TPU lane count, compiled."""
     from repro.core.schedule_jax import _decide_tiled
-    sh = one_chip
     sd = _state(sh, T_PAD) + (_spec(sh, (T_PAD, R)), _spec(sh, (T_PAD, H, R)),
                               _spec(sh, (T_PAD, K, R)))
     jd = (_spec(sh, (LANES, 2 * R + 2)),
@@ -92,9 +88,40 @@ def test_tiled_decide_compiles_with_eight_lanes(one_chip, m_pad):
     tabs = (_spec(sh, (1, 1, 1)),) * 6
     rows = _spec(sh, (LANES, T_PAD, m_pad))
     valid = _spec(sh, (LANES, T_PAD // 64), jnp.bool_)
-    c = _decide_tiled.lower(sd, jd, tabs, rows, valid, T=T, d1=D1,
-                            use_cache=True, mono=0, use_tabs=False).compile()
+    return _decide_tiled.lower(sd, jd, tabs, rows, valid, T=T, d1=D1,
+                               use_cache=True, mono=0,
+                               use_tabs=False).compile()
+
+
+def test_legacy_decide_launch_holds_the_kernel(one_chip):
+    """The single-arrival launch on TPU (``use_pallas=True``) carries the
+    Mosaic kernel, not the interpreter or a jnp stand-in."""
+    assert "tpu_custom_call" in _decide_one_launch(one_chip).as_text()
+
+
+@pytest.mark.parametrize("m_pad", [64, 128])
+def test_tiled_decide_compiles_with_eight_lanes(one_chip, m_pad):
+    """The burst launch at the TPU lane count."""
+    c = _tiled_launch(one_chip, m_pad)
     assert c.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("program", ["_decide_tiled", "_decide_one"])
+def test_row_build_lowers_without_search_or_gather(one_chip, program):
+    """Lowered for the TPU, the COST-row build (scope ``decide.rows``)
+    holds no binary search and no gather: the server sort carries the
+    capacities and the greedy-cost lookup is dense masked reductions
+    (``schedule_jax._greedy_cost``), the form the chip runs fastest."""
+    c = (_tiled_launch(one_chip, 128) if program == "_decide_tiled"
+         else _decide_one_launch(one_chip))
+    rows = []
+    for line in c.as_text().splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and "decide.rows" in m.group(1).split("/"):
+            rows.append((line, m.group(1)))
+    assert any(" sort(" in line for line, _ in rows)
+    assert not [p for _, p in rows if "searchsorted" in p]
+    assert not [line for line, _ in rows if " gather(" in line]
 
 
 def test_accept_launches_compile(one_chip):
