@@ -153,21 +153,14 @@ def _price_pow(ratio: jax.Array, x: jax.Array) -> jax.Array:
     return jnp.exp(x * jnp.log(ratio))
 
 
-def _sort_gather(unit: jax.Array, cap: jax.Array, with_order: bool):
-    """Servers cheapest-first along the last axis as an ``argsort`` and
-    two gathers: ``(scost, scap[, order])``.  The XLA CPU form."""
-    order = jnp.argsort(unit, axis=-1, stable=True)
-    out = (jnp.take_along_axis(unit, order, axis=-1),
-           jnp.take_along_axis(cap, order, axis=-1))
-    return out + (order,) if with_order else out
-
-
-def _sort_carry(unit: jax.Array, cap: jax.Array, with_order: bool):
-    """``_sort_gather``'s values from one stable sort keyed on ``unit``
-    that carries ``cap`` (and, ``with_order``, the server index) beside
-    it, with no gather after it.  ``jnp.argsort`` is that sort with the
-    index alone, so the order, ties included, is the same.  The TPU
-    form."""
+def _sort_servers(unit: jax.Array, cap: jax.Array, with_order: bool):
+    """``(scost, scap[, order])``: per-slot unit costs and capacities in
+    cheapest-first server order along the last axis, ties kept in server
+    order.  One stable sort keyed on ``unit`` carries ``cap`` (and, with
+    ``with_order``, the server index) beside it, with no gather after it:
+    ``jnp.argsort`` is that sort with the index alone, so the order is
+    the same, and a data-dependent gather is the slowest thing a TPU does
+    over a 100-wide server axis."""
     ops = (unit, cap)
     if with_order:
         ops += (jax.lax.broadcasted_iota(
@@ -175,16 +168,6 @@ def _sort_carry(unit: jax.Array, cap: jax.Array, with_order: bool):
             unit.ndim - 1),)
     return jax.lax.sort(ops, dimension=unit.ndim - 1, is_stable=True,
                         num_keys=1)
-
-
-def _sort_servers(unit: jax.Array, cap: jax.Array, with_order: bool):
-    """``(scost, scap[, order])``: per-slot unit costs and capacities in
-    cheapest-first server order, ties kept in server order.  Chosen when
-    the program is lowered: a data-dependent gather is the slowest thing
-    a TPU does over a 100-wide server axis; XLA CPU gathers cheaply."""
-    return jax.lax.platform_dependent(
-        unit, cap, tpu=functools.partial(_sort_carry, with_order=with_order),
-        default=functools.partial(_sort_gather, with_order=with_order))
 
 
 def _prefix_tables_jnp(prices: jax.Array, headroom: jax.Array,
@@ -251,15 +234,26 @@ def _greedy_cost_dense(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
                      jnp.where(counts <= ccap[..., -1:], vals, jnp.inf))
 
 
+# The greedy-cost lookup each platform lowers (``_greedy_cost``'s
+# ``platform_dependent`` branches; platforms not named take "default"),
+# each with the obs counter of the COST-row tiles built by it
+# (``_rows_lookup_counter``).  A gather loop is what costs on the TPU;
+# XLA CPU runs the dense form over ten times slower than the search.
+_GREEDY_COST_FORMS = {
+    "tpu": (_greedy_cost_dense, "decide.rows_dense_tiles"),
+    "default": (_greedy_cost_search, "decide.rows_search_tiles"),
+}
+
+
 def _greedy_cost(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
                  counts: jax.Array) -> jax.Array:
     """Greedy (cheapest-first) deployment cost for ``counts`` (..., M) at
     every slot, from (..., S) prefix tables.  +inf where counts exceed
-    capacity.  The TPU lowers the dense form (a gather loop is what costs
-    there), every other platform the binary search."""
-    return jax.lax.platform_dependent(ccap, ccost, scost, counts,
-                                      tpu=_greedy_cost_dense,
-                                      default=_greedy_cost_search)
+    capacity.  The form is the lowering platform's in
+    ``_GREEDY_COST_FORMS``."""
+    return jax.lax.platform_dependent(
+        ccap, ccost, scost, counts,
+        **{p: fn for p, (fn, _) in _GREEDY_COST_FORMS.items()})
 
 
 def _greedy_place_jnp(order: jax.Array, scap: jax.Array, ccap: jax.Array,
@@ -285,9 +279,8 @@ def _prefix_tables_b(prices: jax.Array, headroom: jax.Array,
     prices/headroom: (TILE, S, R) shared across lanes; demand: (B, R) per
     lane.  Returns (scost, ccap, ccost), each (B, TILE, S) — the greedy
     cost tables only (placement order is extracted by ``_place_slots``,
-    never in the decision loop).  On the TPU the sort carries each
-    server's capacity beside its unit cost and no index
-    (``_sort_servers``)."""
+    never in the decision loop).  The sort carries each server's
+    capacity beside its unit cost and no index (``_sort_servers``)."""
     unit = (prices[None] * demand[:, None, None, :]).sum(axis=3)
     safe = jnp.where(demand > 0, demand, 1.0)
     per_r = jnp.where(demand[:, None, None, :] > 0,
@@ -1400,12 +1393,11 @@ def _lane_tables(chunk, caches, state, psd, lanes, b_pad, T, dtype):
 
 
 def _rows_lookup_counter(x: jax.Array) -> str:
-    """The obs counter of the COST-row tiles a launch built: the launch
-    on ``x``'s device lowered ``_greedy_cost``'s dense form on a TPU and
-    its binary search elsewhere."""
-    if next(iter(x.devices())).platform == "tpu":
-        return "decide.rows_dense_tiles"
-    return "decide.rows_search_tiles"
+    """The obs counter of the COST-row tiles a launch built: that of the
+    form ``_GREEDY_COST_FORMS`` gives the platform of ``x``'s device."""
+    platform = next(iter(x.devices())).platform
+    forms = _GREEDY_COST_FORMS
+    return forms.get(platform, forms["default"])[1]
 
 
 def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,

@@ -1,11 +1,11 @@
 """The COST-row build's two lowerings give the same numbers, bit for bit.
 
-A TPU lowers the server sort as one stable sort that carries the
-capacities (``_sort_carry``) and the greedy-cost lookup as dense masked
-reductions (``_greedy_cost_dense``); every other platform lowers an
-argsort with gathers (``_sort_gather``) and a binary search
-(``_greedy_cost_search``).  These tests run both forms on the CPU: the
-helpers directly, then the whole engine with the TPU forms swapped in.
+Every platform sorts the servers with one stable sort that carries the
+capacities (``_sort_servers``), which must equal an ``argsort`` with
+gathers.  A TPU lowers the greedy-cost lookup as dense masked reductions
+(``_greedy_cost_dense``), every other platform as a binary search
+(``_greedy_cost_search``).  These tests run both lookups on the CPU: the
+helpers directly, then the whole engine with the TPU's swapped in.
 """
 import jax
 import jax.numpy as jnp
@@ -68,12 +68,13 @@ def test_dense_row_lookup_equals_search(case):
         with jax.enable_x64(x64):
             dt = jnp.float64 if x64 else jnp.float32
             u, c, n = (jnp.asarray(a, dt) for a in (unit, cap, counts))
+            order = jnp.argsort(u, axis=-1, stable=True)
+            want = (jnp.take_along_axis(u, order, axis=-1),
+                    jnp.take_along_axis(c, order, axis=-1), order)
             for with_order in (False, True):
-                want = jax.jit(sj._sort_gather, static_argnums=2)(
+                got = jax.jit(sj._sort_servers, static_argnums=2)(
                     u, c, with_order)
-                got = jax.jit(sj._sort_carry, static_argnums=2)(
-                    u, c, with_order)
-                assert len(got) == len(want) == 2 + with_order
+                assert len(got) == 2 + with_order
                 for g, w in zip(got, want):
                     assert g.dtype == w.dtype
                     assert np.array_equal(np.asarray(g), np.asarray(w))
@@ -133,19 +134,25 @@ def _same_schedule(a, b):
 
 
 def test_engine_with_tpu_row_lookup_equals_search(monkeypatch):
-    """The whole engine with the TPU's sort and lookup swapped in for the
-    CPU's: the burst launches (row cache and order cache on at this
-    size), the sequential re-solves and ``_decide_one`` decide, place and
-    cost every job as the CPU forms do."""
+    """The whole engine with the TPU's lookup swapped in for the CPU's:
+    the burst launches (row cache and order cache on at this size), the
+    sequential re-solves and ``_decide_one`` decide, place and cost every
+    job as the binary search does, and the rows built are counted under
+    the dense form's counter."""
     search = _run_episode()
-    monkeypatch.setattr(sj, "_sort_gather", sj._sort_carry)
-    monkeypatch.setattr(sj, "_greedy_cost_search", sj._greedy_cost_dense)
+    forms = sj._GREEDY_COST_FORMS
+    monkeypatch.setitem(forms, "default", forms["tpu"])
     jax.clear_caches()
+    ob = obslib.Obs()
     try:
-        dense = _run_episode()
+        with obslib.activate(ob):
+            dense = _run_episode()
     finally:
         monkeypatch.undo()
         jax.clear_caches()
+    c = ob.metrics.snapshot()["counters"]
+    assert c["decide.rows_dense_tiles"] > 0
+    assert c.get("decide.rows_search_tiles", 0) == 0
     for s, d in zip(search[:2], dense[:2]):
         assert s.accepted.keys() == d.accepted.keys()
         assert s.total_utility == d.total_utility             # exact
