@@ -41,7 +41,8 @@ runs float32 (f64 is unsupported there).  Single arrivals on TPU take the
 legacy monolithic core with the Pallas min-plus sweep kernel
 (``use_pallas=True``), which Mosaic compiles whenever the launch is
 lowered for a TPU (``kernels.minplus.ops.minplus_sweep``); bursts take
-the tiled core.
+the tiled core, whose slot scan runs on a TPU as one Pallas kernel call
+per visited tile (``kernels.minplus.dp_tile``, ``_SLOT_SCAN_FORMS``).
 
 ``dp_sweep_jax`` (the seed's DP-only entry point) is kept for
 micro-benches and backward compatibility.
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.minplus.dp_tile import dp_tile_pallas
 from ..kernels.minplus.monotone import (PATH_CHAIN, PATH_DNC, PATH_PLATEAU,
                                         convex_certificate, monotone_dnc_step,
                                         plateau_step_unrolled, run_count)
@@ -237,7 +239,7 @@ def _greedy_cost_dense(ccap: jax.Array, ccost: jax.Array, scost: jax.Array,
 # The greedy-cost lookup each platform lowers (``_greedy_cost``'s
 # ``platform_dependent`` branches; platforms not named take "default"),
 # each with the obs counter of the COST-row tiles built by it
-# (``_rows_lookup_counter``).  A gather loop is what costs on the TPU;
+# (``_form_counter``).  A gather loop is what costs on the TPU;
 # XLA CPU runs the dense form over ten times slower than the search.
 _GREEDY_COST_FORMS = {
     "tpu": (_greedy_cost_dense, "decide.rows_dense_tiles"),
@@ -373,6 +375,118 @@ def _dummy_tabs(dtype_name: str):
     return (z,) * 6
 
 
+def _slot_scan(rows_tile, u_tile, prev, best, best_t, paths, t0, a, d_tot,
+               *, T: int, mono: int):
+    """One tile's slot scan in XLA: a ``lax.scan`` of
+    ``minplus_chain_step`` (or a monotone branch, chosen once for the
+    tile) and the payoff pick, for every platform but the TPU.
+
+    rows_tile (B, TILE, M), u_tile (B, TILE), prev (B, d1) the carry,
+    best/best_t (B,) the pick so far, paths (3,) the tiles per branch,
+    t0 the tile's first slot, a/d_tot (B,) i32.  Returns ``(prev, best,
+    best_t, cols (B, TILE, d1), paths)``."""
+    n, M = rows_tile.shape[1:]
+    r_max = max(16, M // 4)
+    ts_tile = t0 + jnp.arange(n, dtype=jnp.int32)
+    a_min = jnp.min(a)
+
+    # Monotone min-plus dispatch, decided ONCE for the whole tile:
+    # every slot row in the tile must qualify, because a per-slot
+    # branch costs more in dispatch than the fast path saves.  The
+    # plateau gate (run_count <= r_max, no NaN / -inf) is exactly the
+    # soundness condition of ``plateau_step_unrolled``; identity rows
+    # of dead slots have 2 runs and never block it.
+    if mono:
+        rt = rows_tile[0]
+        clean = jnp.all((rt == rt) & (rt > -jnp.inf))
+        plat_ok = clean & jnp.all(jax.vmap(run_count)(rt) <= r_max)
+        if mono >= 2:
+            conv_ok = clean & jnp.all(jax.vmap(convex_certificate)(rt))
+            branch = jnp.where(
+                conv_ok, PATH_DNC,
+                jnp.where(plat_ok, PATH_PLATEAU, PATH_CHAIN))
+        else:
+            branch = jnp.where(plat_ok, PATH_PLATEAU, PATH_CHAIN)
+    else:
+        branch = jnp.int32(PATH_CHAIN)
+    paths = paths.at[branch].add(1)
+
+    def slot(carry, x):
+        prev, best, best_t = carry
+        row, u_t, t = x
+
+        def live(_):
+            if mono >= 2:
+                def _dnc():
+                    out, ovf = monotone_dnc_step(row[0], prev[0])
+                    return jax.lax.cond(
+                        ovf,
+                        lambda: minplus_chain_step(row, prev),
+                        lambda: out[None])
+                new = jax.lax.switch(branch, [
+                    _dnc,
+                    lambda: plateau_step_unrolled(
+                        row[0], prev[0], r_max)[None],
+                    lambda: minplus_chain_step(row, prev)])
+            elif mono:
+                new = jax.lax.cond(
+                    branch == PATH_PLATEAU,
+                    lambda: plateau_step_unrolled(
+                        row[0], prev[0], r_max)[None],
+                    lambda: minplus_chain_step(row, prev))
+            else:
+                new = minplus_chain_step(row, prev)
+            costD = jnp.take_along_axis(new, d_tot[:, None], axis=1)[:, 0]
+            pay = jnp.where(jnp.isfinite(costD) & (t >= a) & (t < T),
+                            u_t - costD, -jnp.inf)
+            switch = pay > best + _PAY_EPS
+            return (new, jnp.where(switch, pay, best),
+                    jnp.where(switch, t, best_t))
+
+        def dead(_):
+            # slots before every lane's arrival (or past the horizon)
+            # have the identity row [0, inf, ...]: the chain step would
+            # return ``prev`` bit-for-bit, so skip it at runtime — with
+            # single-lane launches this skips the DP for the whole
+            # pre-arrival prefix of the first tile
+            return (prev, best, best_t)
+
+        new, best, best_t = jax.lax.cond(
+            (t >= a_min) & (t < T), live, dead, None)
+        return (new, best, best_t), new
+
+    (prev, best, best_t), cols = jax.lax.scan(
+        slot, (prev, best, best_t),
+        (jnp.swapaxes(rows_tile, 0, 1), u_tile.T, ts_tile))
+    return prev, best, best_t, jnp.swapaxes(cols, 0, 1), paths
+
+
+def _slot_scan_kernel(rows_tile, u_tile, prev, best, best_t, paths, t0, a,
+                      d_tot, *, T: int, mono: int):
+    """``_slot_scan`` as one Pallas kernel call per tile
+    (``kernels.minplus.dp_tile``), the TPU's form: bit-identical DP
+    columns and pick, no per-slot ``cond``, and no monotone branch
+    (``mono`` is ignored and ``paths`` is returned unchanged)."""
+    del mono
+    dt = prev.dtype
+    cols, best, best_t = dp_tile_pallas(rows_tile, u_tile, prev, best,
+                                        best_t, a, d_tot, t0, T=T,
+                                        eps=_PAY_EPS)
+    cols = cols.astype(dt)
+    return cols[:, -1], best.astype(dt), best_t, cols, paths
+
+
+# The slot scan each platform lowers (the tile body's
+# ``platform_dependent`` branches; platforms not named take "default"),
+# each with the obs counter of the visited tiles it ran
+# (``_form_counter``).  On the TPU the scan's per-slot ``cond``, slices
+# and gathers cost far more than the VPU work of the min-plus step.
+_SLOT_SCAN_FORMS = {
+    "tpu": (_slot_scan_kernel, "decide.dp_kernel_tiles"),
+    "default": (_slot_scan, "decide.dp_scan_tiles"),
+}
+
+
 def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
                        d1: int, use_cache: bool, mono: int,
                        use_tabs: bool):
@@ -409,17 +523,21 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         branch (``REPRO_MONOTONE_DNC``).  Levels > 0 require a single
         lane; the branch is chosen ONCE PER TILE (per-slot dispatch costs
         more than it saves) and every branch produces bit-identical DP
-        values (see ``kernels.minplus.monotone``).
+        values (see ``kernels.minplus.monotone``).  Only the scan form of
+        ``_SLOT_SCAN_FORMS`` has the branches: the TPU's ignores ``mono``.
 
     Returns (best_t i32 (-1 = reject), payoff, total_cost, d_left i32,
     d_slots (B, T_pad) i32, rows (B, T_pad, M) — the refreshed row cache —
     k0, k_end i32: the visited tile range [k0, k_end), paths (3,) i32 —
-    per-branch processed-tile counts [dnc, plateau, chain]).
+    per-branch counts [dnc, plateau, chain] of the tiles the scan form
+    ran, all 0 where the tile kernel ran them).
 
     Device scopes: each tile's COST rows (built or served from the row
     cache) run under ``decide.rows``; the rest of the tile loop — its
     exit test and live cost floor, the slot scan with its payoff pick,
-    the buffer writes — under ``decide.dp``.
+    the buffer writes — under ``decide.dp``.  The slot scan is the
+    lowering platform's form in ``_SLOT_SCAN_FORMS``: on a TPU one
+    Pallas kernel call per visited tile.
     """
     g, v, wcaps, scaps, U1, U2, L1, L2, pmin, p_pad, q_pad = sd
     resbw, WZ, u, usmax, meta, lb = jd
@@ -438,7 +556,6 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
     K = v.shape[1]
     if mono:
         assert B == 1, "monotone dispatch is single-lane only"
-    r_max = max(16, M // 4)
 
     Wf = W.astype(dt)
     deploy_target = jnp.minimum(Z, W).astype(dt)                 # (B, M)
@@ -496,7 +613,6 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         return jnp.where(dead[:, :, None] & (ms > 0)[None, None, :],
                          jnp.inf, rows)
 
-    a_min = jnp.min(a)
     init_col = jnp.full((B, d1), jnp.inf, dt).at[:, 0].set(0.0)
     if use_cache:
         rows_buf0 = rows_init
@@ -561,79 +677,12 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
             else:
                 rows_tile = rows_for_tile(t0)
         u_tile = jax.lax.dynamic_slice(u, (zero, t0), (B, TILE))
-        ts_tile = t0 + jnp.arange(TILE, dtype=jnp.int32)
-
-        # Monotone min-plus dispatch, decided ONCE for the whole tile:
-        # every slot row in the tile must qualify, because a per-slot
-        # branch costs more in dispatch than the fast path saves.  The
-        # plateau gate (run_count <= r_max, no NaN / -inf) is exactly the
-        # soundness condition of ``plateau_step_unrolled``; identity rows
-        # of dead slots have 2 runs and never block it.
-        if mono:
-            rt = rows_tile[0]
-            clean = jnp.all((rt == rt) & (rt > -jnp.inf))
-            plat_ok = clean & jnp.all(jax.vmap(run_count)(rt) <= r_max)
-            if mono >= 2:
-                conv_ok = clean & jnp.all(jax.vmap(convex_certificate)(rt))
-                branch = jnp.where(
-                    conv_ok, PATH_DNC,
-                    jnp.where(plat_ok, PATH_PLATEAU, PATH_CHAIN))
-            else:
-                branch = jnp.where(plat_ok, PATH_PLATEAU, PATH_CHAIN)
-        else:
-            branch = jnp.int32(PATH_CHAIN)
-        paths = paths.at[branch].add(1)
-
-        def slot(carry, x):
-            prev, best, best_t = carry
-            row, u_t, t = x
-
-            def live(_):
-                if mono >= 2:
-                    def _dnc():
-                        out, ovf = monotone_dnc_step(row[0], prev[0])
-                        return jax.lax.cond(
-                            ovf,
-                            lambda: minplus_chain_step(row, prev),
-                            lambda: out[None])
-                    new = jax.lax.switch(branch, [
-                        _dnc,
-                        lambda: plateau_step_unrolled(
-                            row[0], prev[0], r_max)[None],
-                        lambda: minplus_chain_step(row, prev)])
-                elif mono:
-                    new = jax.lax.cond(
-                        branch == PATH_PLATEAU,
-                        lambda: plateau_step_unrolled(
-                            row[0], prev[0], r_max)[None],
-                        lambda: minplus_chain_step(row, prev))
-                else:
-                    new = minplus_chain_step(row, prev)
-                costD = jnp.take_along_axis(new, d_tot[:, None],
-                                            axis=1)[:, 0]
-                pay = jnp.where(jnp.isfinite(costD) & (t >= a) & (t < T),
-                                u_t - costD, -jnp.inf)
-                switch = pay > best + _PAY_EPS
-                return (new, jnp.where(switch, pay, best),
-                        jnp.where(switch, t, best_t))
-
-            def dead(_):
-                # slots before every lane's arrival (or past the horizon)
-                # have the identity row [0, inf, ...]: the chain step
-                # would return ``prev`` bit-for-bit, so skip it at
-                # runtime — with single-lane launches this skips the DP
-                # for the whole pre-arrival prefix of the first tile
-                return (prev, best, best_t)
-
-            new, best, best_t = jax.lax.cond(
-                (t >= a_min) & (t < T), live, dead, None)
-            return (new, best, best_t), new
-
-        (prev, best, best_t), cols = jax.lax.scan(
-            slot, (prev, best, best_t),
-            (jnp.swapaxes(rows_tile, 0, 1), u_tile.T, ts_tile))
-        cost_buf = jax.lax.dynamic_update_slice(
-            cost_buf, jnp.swapaxes(cols, 0, 1), (zero, t0, zero))
+        prev, best, best_t, cols, paths = jax.lax.platform_dependent(
+            rows_tile, u_tile, prev, best, best_t, paths, t0, a, d_tot,
+            **{p: functools.partial(fn, T=T, mono=mono)
+               for p, (fn, _) in _SLOT_SCAN_FORMS.items()})
+        cost_buf = jax.lax.dynamic_update_slice(cost_buf, cols,
+                                                (zero, t0, zero))
         rows_buf = jax.lax.dynamic_update_slice(
             rows_buf, rows_tile, (zero, t0, zero))
         return k + 1, prev, best, best_t, paths, cost_buf, rows_buf
@@ -1392,11 +1441,11 @@ def _lane_tables(chunk, caches, state, psd, lanes, b_pad, T, dtype):
     return tabs, lane_tabs, True
 
 
-def _rows_lookup_counter(x: jax.Array) -> str:
-    """The obs counter of the COST-row tiles a launch built: that of the
-    form ``_GREEDY_COST_FORMS`` gives the platform of ``x``'s device."""
+def _form_counter(forms: dict, x: jax.Array) -> str:
+    """The obs counter of the form ``forms`` (``_GREEDY_COST_FORMS``,
+    ``_SLOT_SCAN_FORMS``) gives the platform of ``x``'s device: the
+    platform the launch that made ``x`` was lowered for."""
     platform = next(iter(x.devices())).platform
-    forms = _GREEDY_COST_FORMS
     return forms.get(platform, forms["default"])[1]
 
 
@@ -1484,11 +1533,14 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
             # where every lane holds it (``_decide_tiled_core``)
             served = (int(valid0[:, k0:k_end].all(axis=0).sum())
                       if use_cache else 0)
-            _obs.inc(_rows_lookup_counter(rows_buf), k_end - k0 - served)
+            _obs.inc(_form_counter(_GREEDY_COST_FORMS, rows_buf),
+                     k_end - k0 - served)
+            _obs.inc(_form_counter(_SLOT_SCAN_FORMS, rows_buf), k_end - k0)
             _obs.observe("decide.early_exit_frac",
                          (k_end - k0) / max(n_tiles, 1))
-            # tiles per min-plus branch (bit-identical paths): how often
-            # the monotone fast paths fired against the chain fallback
+            # tiles per min-plus branch of the scan form (bit-identical
+            # paths): how often the monotone fast paths fired against the
+            # chain fallback; 0 where the tile kernel ran
             _obs.inc("decide.path_dnc", int(pth[0]))
             _obs.inc("decide.path_plateau", int(pth[1]))
             _obs.inc("decide.path_chain", int(pth[2]))
@@ -1581,7 +1633,8 @@ def best_schedule_fused(job: Job, state: PriceState, *,
             with _obs.span("launch.fetch", program="_decide_one"):
                 best_t, _, cost, d_left, d_slots, y, z = jax.device_get(out)
             if _obs.ENABLED:
-                _obs.inc(_rows_lookup_counter(out[0]), _pad_tiles(T) // TILE)
+                _obs.inc(_form_counter(_GREEDY_COST_FORMS, out[0]),
+                         _pad_tiles(T) // TILE)
             return _schedule_from_outputs(
                 job, state, int(best_t), float(cost), int(d_left),
                 d_slots, y, z)

@@ -124,6 +124,47 @@ def test_row_build_lowers_without_search_or_gather(one_chip, program):
     assert not [line for line, _ in rows if " gather(" in line]
 
 
+_INSTRUCTION = re.compile(r"^\s*(ROOT )?%\S+ = ")
+_METADATA = re.compile(r"metadata=\{[^}]*\}")
+
+
+@pytest.mark.parametrize("m_pad", [64, 128, 384])
+def test_tiled_slot_scan_is_the_tile_kernel(one_chip, m_pad):
+    """Lowered for the TPU, the burst launch's slot scan is one Pallas
+    kernel call per tile under ``decide.dp`` (``_SLOT_SCAN_FORMS``), and
+    no instruction of the launch is named ``minplus``: the benchmark
+    costs ops of that name as the sweep kernel."""
+    text = _tiled_launch(one_chip, m_pad).as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1
+    path = re.search(r'op_name="([^"]*)"', kernels[0]).group(1).split("/")
+    assert "decide.dp" in path and "decide.rows" not in path
+    named = [line for line in text.splitlines() if _INSTRUCTION.match(line)
+             and re.search("minplus", _METADATA.sub("", line), re.I)]
+    assert not named
+
+
+def test_tiled_slot_scan_on_the_cpu_holds_no_custom_call():
+    """The same launch lowered for the CPU keeps the XLA scan."""
+    from repro.core.schedule_jax import _decide_tiled
+    sd = _state(None, T_PAD) + (_spec(None, (T_PAD, R)),
+                                _spec(None, (T_PAD, H, R)),
+                                _spec(None, (T_PAD, K, R)))
+    m_pad = 64
+    jd = (_spec(None, (LANES, 2 * R + 2)),
+          _spec(None, (LANES, 2, m_pad), jnp.int32),
+          _spec(None, (LANES, T_PAD)), _spec(None, (LANES, T_PAD)),
+          _spec(None, (LANES, 4), jnp.int32), _spec(None, (LANES,)))
+    lowered = _decide_tiled.lower(
+        sd, jd, (_spec(None, (1, 1, 1)),) * 6,
+        _spec(None, (LANES, T_PAD, m_pad)),
+        _spec(None, (LANES, T_PAD // 64), jnp.bool_), T=T, d1=D1,
+        use_cache=True, mono=0, use_tabs=False)
+    assert "custom_call" not in lowered.as_text()
+    assert "custom-call" not in lowered.compile().as_text()
+
+
 def test_accept_launches_compile(one_chip):
     """Backtrack and placement, the two launches an accept adds."""
     from repro.core.schedule_jax import _backtrack, _place_slots
