@@ -34,9 +34,8 @@ Compared leaves:
   — never gated (see ``CHURN_SECTIONS``)
 * ``obs.derived.*`` — the flight-recorder probe's deterministic
   efficiency figures (schema v5): ``early_exit_frac`` and
-  ``device_uploads`` gate lower-is-better, ``row_cache_hit_rate``
-  inverted; a drift here is a semantic efficiency regression (the
-  row cache stopped hitting, the early exit stopped firing, full-table
+  ``device_uploads`` gate lower-is-better; a drift here is a semantic
+  efficiency regression (the early exit stopped firing, full-table
   uploads reappeared) even when wall clocks stay within ratio
 
 A section is only ever compared against a like-configured baseline
@@ -85,18 +84,16 @@ CHURN_SECTIONS = ("churn",)
 
 # gated flight-recorder sections (schema v5): the obs probe's derived
 # efficiency figures are deterministic counter ratios, so like churn
-# retention a drift is semantic — the row cache stopped hitting, the
-# early exit stopped firing, or full-table uploads reappeared on the
-# commit path.  ``early_exit_frac`` / ``device_uploads`` are
-# lower-is-better leaves; ``row_cache_hit_rate`` is higher-is-better
-# (inverted like the throughputs).  ``preempted`` and the raw counter
+# retention a drift is semantic — the early exit stopped firing, or
+# full-table uploads reappeared on the commit path.  ``early_exit_frac``
+# / ``device_uploads`` are lower-is-better leaves.  ``preempted`` and
+# the raw counter
 # snapshot are informational — preemption counts track the churn
 # workload, not an efficiency property.
 OBS_SECTIONS = ("obs",)
 
-# the gated derived leaves of the obs section, by direction
+# the gated derived leaves of the obs section (lower is better)
 OBS_LEAVES = ("early_exit_frac", "device_uploads")
-OBS_RATE_LEAVES = ("row_cache_hit_rate",)
 
 
 def _leaves(doc: dict) -> Iterator[Tuple[str, float]]:
@@ -145,11 +142,6 @@ def _rate_leaves(doc: dict) -> Iterator[Tuple[str, float]]:
                 continue
             for variant, ret in sorted(per_variant.items()):
                 yield f"{section}.retention.{sched}.{variant}", float(ret)
-    for section in OBS_SECTIONS:
-        derived = doc.get(section, {}).get("derived", {})
-        for name in OBS_RATE_LEAVES:
-            if name in derived:
-                yield f"{section}.derived.{name}", float(derived[name])
 
 
 def _section_quick(doc: dict, section: str):

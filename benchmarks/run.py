@@ -37,8 +37,8 @@ vs plateau across band widths, convex and adversarial rows); its
 per-case p50s are regression-gated.  ``obs`` (schema v5) runs a seeded
 OASiS-on-jax episode plus a reactive episode under fleet churn with the
 ``repro.obs`` flight recorder installed and records the counter
-snapshot plus derived health figures (row-cache hit rate, early-exit
-tile fraction, device uploads, preemptions) — the derived leaves are
+snapshot plus derived health figures (early-exit tile fraction,
+device uploads, preemptions) — the derived leaves are
 regression-gated so a silent efficiency loss (cache stops hitting,
 early exit stops firing, uploads reappear on the commit path) fails CI
 even when wall clocks stay within ratio.  Sections *merge* into an
@@ -360,10 +360,8 @@ def _obs_probe(quick: bool = False):
     both run with a ``repro.obs`` recorder installed.
 
     Returns (CSV rows, tracked record).  The record carries the raw
-    counter snapshot and four derived health figures:
+    counter snapshot and three derived health figures:
 
-    * ``row_cache_hit_rate``   — burst re-solve tiles served from the
-      per-job ``RowCache`` (higher is better; gated inverted)
     * ``early_exit_frac``      — DP tiles actually visited / horizon
       tiles (lower is better: the monotone early-exit is working)
     * ``device_uploads``       — full-table host->device uploads on the
@@ -380,9 +378,8 @@ def _obs_probe(quick: bool = False):
     from repro.sim.fleet import make_fleet_trace
     from repro.sim.workload import make_cluster, make_jobs
 
-    # full mode needs T >= 3 TILE-slot blocks (TILE=64): with a 2-tile
-    # horizon every commit dirties the visited tile and the row-cache
-    # hit rate is identically zero — no signal to gate
+    # full mode spans 3 TILE-slot blocks (TILE=64), so the early exit
+    # has tiles to skip
     T, HK, n_jobs = (48, 6, 24) if quick else (192, 10, 64)
     cluster = make_cluster(T=T, H=HK, K=HK)
     jobs = make_jobs(n_jobs, T=T, seed=0, small=True)
@@ -396,11 +393,8 @@ def _obs_probe(quick: bool = False):
     engine.run(cluster, jobs, scheduler="dorm", fleet=fleet, obs=ob)
     wall = time.perf_counter() - t0
     c = dict(ob.metrics.snapshot()["counters"])
-    tiles_total = c.get("decide.cache_tiles_total", 0.0)
     tiles_horizon = c.get("decide.tiles_horizon", 0.0)
     derived = {
-        "row_cache_hit_rate": (c.get("decide.cache_tiles_valid", 0.0)
-                               / tiles_total) if tiles_total else 0.0,
         "early_exit_frac": (c.get("decide.tiles_visited", 0.0)
                             / tiles_horizon) if tiles_horizon else 1.0,
         "device_uploads": c.get("price.device_uploads", 0.0),
@@ -409,7 +403,6 @@ def _obs_probe(quick: bool = False):
     tracked = {"T": T, "H": HK, "K": HK, "n_jobs": n_jobs,
                "quick": bool(quick), "counters": c, "derived": derived}
     rows = [f"obs_probe[jobs={n_jobs};T={T}],{wall * 1e6:.0f},"
-            f"cache_hit={derived['row_cache_hit_rate']:.3f};"
             f"early_exit={derived['early_exit_frac']:.3f};"
             f"uploads={derived['device_uploads']:.0f};"
             f"preempted={derived['preempted']:.0f}"]

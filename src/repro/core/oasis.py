@@ -113,10 +113,7 @@ class OASiS:
           a non-positive maximum stays non-positive;
         * an ACCEPTED candidate is used as-is only while no other job from
           the batch has been admitted; once prices move it is re-solved
-          against the updated state — *incrementally*: the speculative
-          pass's COST rows are cached per job (``RowCache``), the price
-          state's dirty-slot log says which slots earlier commits touched,
-          and the re-solve recomputes only those tiles.
+          from scratch against the updated state (``best_schedule_fused``).
 
         The result is identical, job for job, to calling ``on_arrival`` in
         sequence (stable arrival order).
@@ -156,14 +153,10 @@ class OASiS:
                     out[i] = self._resolve(jobs[i], sched)
                     prices_moved = prices_moved or out[i] is not None
                 else:
-                    # prices moved: incremental re-solve over cached rows
+                    # prices moved: re-solve at the current prices
                     t0 = time.perf_counter()
-                    with _obs.span("decide.row_cache_sync",
-                                   jid=jobs[i].jid):
-                        pend.cache.sync(self.state)
                     with _obs.span("decide.resolve", jid=jobs[i].jid):
-                        sched = best_schedule_fused(jobs[i], self.state,
-                                                    row_cache=pend.cache)
+                        sched = best_schedule_fused(jobs[i], self.state)
                     # the speculative batch share spent on this job is real
                     # per-decision cost too — don't under-report latency
                     self.decision_seconds.append(

@@ -282,7 +282,8 @@ class PriceState:
         # bumped on every commit/release (consumers may key caches on it)
         self.version = 0
         # dirty-slot log: (version, t0, t1) per commit/release slot window,
-        # so row caches can invalidate only the slots a commit touched.
+        # so caches keyed on the version can refresh only the slots a
+        # commit touched.
         # ``_dirty_floor`` is the oldest version the log still covers —
         # ``dirty_spans_since`` answers None (unknowable; invalidate all)
         # for anything older.  advance() and mutable ``g``/``v`` access
@@ -363,7 +364,7 @@ class PriceState:
     def g(self) -> np.ndarray:
         """Worker-pool allocation (T, H, R), host numpy.  Hands out the
         mutable mirror, so the device residency is conservatively dropped
-        (re-uploaded on next ``device_state``) and existing row caches are
+        (re-uploaded on next ``device_state``) and version-keyed caches are
         conservatively invalidated (dirty floor moves past ``version``)."""
         self._dev = None
         self._dirty_log.clear()
@@ -557,23 +558,11 @@ class PriceState:
         unknowable — ``version`` predates the log floor (log trimmed, a
         window slide, or mutable ``g``/``v`` access) — in which case the
         caller must invalidate everything.  Commit/release windows are
-        logged in :meth:`_apply`; row caches consume this via
-        ``RowCache.sync``."""
+        logged in :meth:`_apply`; the engine's padded-state cache consumes
+        this (``schedule_jax._padded_state``)."""
         if version < self._dirty_floor:
             return None
         return [(t0, t1) for v, t0, t1 in self._dirty_log if v > version]
-
-    def patch_spans(self, version: int, limit: int = 8):
-        """Dirty spans in the form an incremental table patcher consumes:
-        the :meth:`dirty_spans_since` list when it has at most ``limit``
-        entries, else ``None`` — more spans than that and span-by-span
-        patching launches more kernels than one full rebuild.  Shared by
-        the engine's padded-state price-table cache and the per-job
-        sorted-order/cumsum cache (``schedule_jax._sorted_fill``)."""
-        spans = self.dirty_spans_since(version)
-        if spans is None or len(spans) > limit:
-            return None
-        return spans
 
     def headroom_workers(self, t: int) -> np.ndarray:
         return self.cluster.worker_caps - self._g_host[t]

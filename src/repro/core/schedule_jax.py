@@ -22,9 +22,10 @@ lane axis so an entire arrival burst is one device launch:
   up).  The reference tie rule (``> best + 1e-12``) therefore cannot
   switch on any skipped slot and decisions stay bit-identical to
   ``best_schedule_ref``;
-* COST rows can be served from a :class:`RowCache` — a commit only moves
-  prices inside the committed slot window, so re-solves (the sequential
-  half of ``OASiS.on_arrivals``) recompute only dirtied tiles.
+* each visited tile's COST rows are built inside the launch from slices
+  of the padded price tables (``_padded_state``, cached per state
+  version): a launch's only operands are that state and the lanes'
+  job arrays.
 
 Placement is extracted by a second, small jit (``_place_slots``) over
 just the slots of the accepted schedule that actually deploy, so the
@@ -88,7 +89,7 @@ _PAY_EPS = 1e-12        # payoff tie epsilon — same as the reference path
 # prefix sums can never push a computed cost below it
 _LB_MARGIN = 0.999
 # split-tie band for the backtrack argmin: XLA vectorizes the same f64
-# pipeline differently per launch shape (lane count, cache path), so two
+# pipeline differently per launch shape (lane count), so two
 # launches over identical state can disagree in the LAST ULPS of a DP
 # cell.  An exact argmin then flips between equally-optimal splits and
 # the committed placements — hence the whole price trajectory — fork
@@ -104,8 +105,8 @@ _SPLIT_TOL = 1e-12
 # single-core CPU backend the DP sweep is memory-bandwidth bound and lane
 # fusion scales SUPERLINEARLY in wall clock (8 fused lanes measured ~2.7x
 # the cost of 8 singleton launches at paper-10x shapes), so bursts there
-# decide lane-by-lane — still speculative, still one RowCache per job —
-# while parallel backends get real fusion.  Override with REPRO_BURST_LANES.
+# decide lane-by-lane — still speculative — while parallel backends get
+# real fusion.  Override with REPRO_BURST_LANES.
 _MAX_LANES = int(os.environ.get(
     "REPRO_BURST_LANES", "8" if jax.default_backend() == "tpu" else "1"))
 
@@ -297,47 +298,6 @@ def _prefix_tables_b(prices: jax.Array, headroom: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Per-job sorted-order / cumsum tables (the "order cache")
-# ---------------------------------------------------------------------------
-
-@jax.jit
-@jax.named_scope("decide.rows")
-def _sorted_fill_lanes(p, q, g, v, wcaps, scaps, resbw):
-    """Full sorted-order/cumsum table set for every lane: 6 arrays
-    (B, T_pad, H|K) — ``(w_scost, w_ccap, w_ccost, s_scost, s_ccap,
-    s_ccost)``.
-
-    ``_prefix_tables_b``'s ops (reduce over R, sort + cumsum along
-    the trailing server axis) touch each slot independently, so TILE
-    slices of these tables are bit-identical to the per-tile tables the
-    decide loop used to build inline — and the per-tile sorts leave
-    the decide launch entirely."""
-    wres, sres = resbw[:, :R], resbw[:, R:2 * R]
-    w = _prefix_tables_b(p, wcaps[None] - g, wres)
-    s = _prefix_tables_b(q, scaps[None] - v, sres)
-    return w + s
-
-
-@functools.partial(jax.jit, static_argnames=("span",))
-@jax.named_scope("decide.rows")
-def _sorted_fill(tabs, p, q, g, v, wcaps, scaps, resbw, t0, span: int):
-    """Patch one dirty slot span of a single lane's (T_pad, S) table set
-    in place — the exact ``_sorted_fill_lanes`` formulas on the span's
-    rows, so the patched tables are bit-identical to a full rebuild at
-    the new state version (per-slot sort cost O(dirty) on re-solves)."""
-    zero = jnp.zeros_like(t0)
-    p_s = jax.lax.dynamic_slice(p, (t0, zero, zero), (span,) + p.shape[1:])
-    q_s = jax.lax.dynamic_slice(q, (t0, zero, zero), (span,) + q.shape[1:])
-    g_s = jax.lax.dynamic_slice(g, (t0, zero, zero), (span,) + g.shape[1:])
-    v_s = jax.lax.dynamic_slice(v, (t0, zero, zero), (span,) + v.shape[1:])
-    wres, sres = resbw[None, :R], resbw[None, R:2 * R]
-    w = _prefix_tables_b(p_s, wcaps[None] - g_s, wres)
-    s = _prefix_tables_b(q_s, scaps[None] - v_s, sres)
-    return tuple(jax.lax.dynamic_update_slice(tab, n[0], (t0, zero))
-                 for tab, n in zip(tabs, w + s))
-
-
-# ---------------------------------------------------------------------------
 # Tiled, batched decision core
 # ---------------------------------------------------------------------------
 
@@ -354,25 +314,6 @@ def _mono_dnc() -> bool:
     measured, and compiling it per shape bucket adds seconds of cold
     latency — the kernel stays fully exercised via ops/tests/benchmarks."""
     return os.environ.get("REPRO_MONOTONE_DNC", "") not in ("", "0")
-
-def _table_max() -> int:
-    """Order-cache footprint ceiling: full sorted-table sets are only
-    built (and thereafter span-patched) when ``T_pad * max(H, K)`` is at
-    most this many slot-server cells.  Above it the one-shot build costs
-    more than it can ever amortize — XLA CPU's stable argsort over a
-    (512, 100) table runs ~26 ms while the early-exit decide loop sorts
-    only the tiles it visits — so big shapes keep the inline per-tile
-    path and small re-solve-heavy shapes (serving windows) get O(dirty)
-    patching.  Env-tunable for the order-cache tests."""
-    return int(os.environ.get("REPRO_ORDER_CACHE_MAX", "16384"))
-
-
-@functools.lru_cache(maxsize=4)
-def _dummy_tabs(dtype_name: str):
-    """Placeholder tabs operand for ``use_tabs=False`` launches (the
-    static flag keeps them out of the compiled program entirely)."""
-    z = jnp.zeros((1, 1, 1), jnp.dtype(dtype_name))
-    return (z,) * 6
 
 
 def _slot_scan(rows_tile, u_tile, prev, best, best_t, paths, t0, a, d_tot,
@@ -487,16 +428,15 @@ _SLOT_SCAN_FORMS = {
 }
 
 
-def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
-                       d1: int, use_cache: bool, mono: int,
-                       use_tabs: bool):
+def _decide_tiled_core(sd, jd, *, T: int, d1: int, mono: int):
     """Alg. 2 decisions for a lane batch, horizon-tiled with exact early
     exit (module docstring).
 
-    sd: PADDED state arrays from ``_pad_state`` (g (T_pad,H,R),
+    sd: PADDED state arrays from ``_padded_state`` (g (T_pad,H,R),
         v (T_pad,K,R), wcaps (H,R), scaps (K,R), U1 (R,), U2 (R,),
         L1 (), L2 (), pmin (T_pad, R) — the per-slot minimum worker
-        price for the live cost floor, precomputed per state version)
+        price for the live cost floor — and the dual price tables
+        p (T_pad,H,R), q (T_pad,K,R), all precomputed per state version)
     jd: lane-batched job arrays —
         resbw (B, 2R+2) = [wres, sres, wbw, psbw],
         WZ (B, 2, M) i32, u (B, T_pad), usmax (B, T_pad) suffix-max of u,
@@ -504,18 +444,6 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         price-free per-chunk-pass lower-bound base from
         ``_cost_lower_bound`` (a live greedy price floor over the
         cheapest feasible slots is multiplied in on device).
-    tabs: per-job sorted-order/cumsum tables from ``_sorted_fill_lanes``
-        — 6 arrays (B, T_pad, H|K) when ``use_tabs``; the decide loop
-        then only slices them, so it runs no prices and no sorts at
-        all.  When ``use_tabs`` is False (the common first-decision
-        path), tabs are (1, 1, 1) dummies and the loop builds each
-        visited tile's tables inline from the cached price tables —
-        sorts only on visited tiles, which the early exit keeps far
-        below T_pad.
-    rows_init/valid_tiles: ``use_cache`` row cache — (B, T_pad, M) rows at
-        the current prices plus a (B, n_tiles) tile-validity mask; a tile
-        is recomputed unless it is valid for EVERY lane.  Scalars when
-        ``use_cache`` is False.
     T: static — the real (unpadded) horizon.
     d1: static — DP columns (padded D_total + 1).
     mono: static — monotone min-plus dispatch level: 0 = chain only,
@@ -526,18 +454,19 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         values (see ``kernels.minplus.monotone``).  Only the scan form of
         ``_SLOT_SCAN_FORMS`` has the branches: the TPU's ignores ``mono``.
 
-    Returns (best_t i32 (-1 = reject), payoff, total_cost, d_left i32,
-    d_slots (B, T_pad) i32, rows (B, T_pad, M) — the refreshed row cache —
+    Returns (best_t (B,) i32 (-1 = reject), payoff (B,), rows
+    (B, T_pad, M) — the visited tiles' COST rows, identity rows
+    elsewhere — cost (B, T_pad, d1) — the visited tiles' DP columns —
     k0, k_end i32: the visited tile range [k0, k_end), paths (3,) i32 —
     per-branch counts [dnc, plateau, chain] of the tiles the scan form
     ran, all 0 where the tile kernel ran them).
 
-    Device scopes: each tile's COST rows (built or served from the row
-    cache) run under ``decide.rows``; the rest of the tile loop — its
-    exit test and live cost floor, the slot scan with its payoff pick,
-    the buffer writes — under ``decide.dp``.  The slot scan is the
-    lowering platform's form in ``_SLOT_SCAN_FORMS``: on a TPU one
-    Pallas kernel call per visited tile.
+    Device scopes: each visited tile's COST-row build runs under
+    ``decide.rows``; the rest of the tile loop — its exit test and live
+    cost floor, the slot scan with its payoff pick, the buffer writes —
+    under ``decide.dp``.  The slot scan is the lowering platform's form
+    in ``_SLOT_SCAN_FORMS``: on a TPU one Pallas kernel call per visited
+    tile.
     """
     g, v, wcaps, scaps, U1, U2, L1, L2, pmin, p_pad, q_pad = sd
     resbw, WZ, u, usmax, meta, lb = jd
@@ -551,7 +480,6 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
     W, Z = WZ[:, 0], WZ[:, 1]                                    # (B, M) i32
     a, nchunks, d_tot = meta[:, 0], meta[:, 1], meta[:, 2]
     dcap = meta[:, 3]
-    tw_scost, tw_ccap, tw_ccost, ts_scost, ts_ccap, ts_ccost = tabs
     H = g.shape[1]
     K = v.shape[1]
     if mono:
@@ -565,40 +493,20 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
     def rows_for_tile(t0):
         """COST_t rows for slots [t0, t0+TILE), all lanes: (B, TILE, M).
 
-        ``use_tabs``: assembled from the cached sorted tables (greedy
-        prefix lookups only — the prices and sorts happened in the
-        table build).  Otherwise the tile's prefix tables are built here
-        from slices of the version-cached price tables, with the SAME
-        ``_prefix_tables_b`` formulas — the two modes are bit-identical
-        (sort + cumsum touch each slot independently)."""
+        The tile's prefix tables are built from slices of the
+        version-cached price tables (``_prefix_tables_b``), so sorts run
+        only on visited tiles, which the early exit keeps far below
+        T_pad."""
         zero = jnp.zeros_like(t0)
-        if use_tabs:
-            w_scost = jax.lax.dynamic_slice(
-                tw_scost, (zero, t0, zero), (B, TILE, H))
-            w_ccap = jax.lax.dynamic_slice(
-                tw_ccap, (zero, t0, zero), (B, TILE, H))
-            w_ccost = jax.lax.dynamic_slice(
-                tw_ccost, (zero, t0, zero), (B, TILE, H))
-            s_scost = jax.lax.dynamic_slice(
-                ts_scost, (zero, t0, zero), (B, TILE, K))
-            s_ccap = jax.lax.dynamic_slice(
-                ts_ccap, (zero, t0, zero), (B, TILE, K))
-            s_ccost = jax.lax.dynamic_slice(
-                ts_ccost, (zero, t0, zero), (B, TILE, K))
-        else:
-            nr = p_pad.shape[2]
-            p_t = jax.lax.dynamic_slice(
-                p_pad, (t0, zero, zero), (TILE, H, nr))
-            q_t = jax.lax.dynamic_slice(
-                q_pad, (t0, zero, zero), (TILE, K, nr))
-            g_t = jax.lax.dynamic_slice(
-                g, (t0, zero, zero), (TILE, H, nr))
-            v_t = jax.lax.dynamic_slice(
-                v, (t0, zero, zero), (TILE, K, nr))
-            w_scost, w_ccap, w_ccost = _prefix_tables_b(
-                p_t, wcaps[None] - g_t, wres)
-            s_scost, s_ccap, s_ccost = _prefix_tables_b(
-                q_t, scaps[None] - v_t, sres)
+        nr = p_pad.shape[2]
+        p_t = jax.lax.dynamic_slice(p_pad, (t0, zero, zero), (TILE, H, nr))
+        q_t = jax.lax.dynamic_slice(q_pad, (t0, zero, zero), (TILE, K, nr))
+        g_t = jax.lax.dynamic_slice(g, (t0, zero, zero), (TILE, H, nr))
+        v_t = jax.lax.dynamic_slice(v, (t0, zero, zero), (TILE, K, nr))
+        w_scost, w_ccap, w_ccost = _prefix_tables_b(
+            p_t, wcaps[None] - g_t, wres)
+        s_scost, s_ccap, s_ccost = _prefix_tables_b(
+            q_t, scaps[None] - v_t, sres)
         Wt = jnp.broadcast_to(Wf[:, None, :], (B, TILE, M))
         w_costs = _greedy_cost(w_ccap, w_ccost, w_scost, Wt)
         pool = s_ccap[..., -1:]                                  # (B, TILE, 1)
@@ -614,13 +522,9 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
                          jnp.inf, rows)
 
     init_col = jnp.full((B, d1), jnp.inf, dt).at[:, 0].set(0.0)
-    if use_cache:
-        rows_buf0 = rows_init
-    else:
-        rows_buf0 = jnp.full((B, T_pad, M), jnp.inf, dt).at[:, :, 0].set(0.0)
+    rows_buf0 = jnp.full((B, T_pad, M), jnp.inf, dt).at[:, :, 0].set(0.0)
     cost_buf0 = jnp.full((B, T_pad, d1), jnp.inf, dt)
     k0 = jnp.min(a).astype(jnp.int32) // TILE
-    t_start = k0 * TILE
 
     # Live early-exit cost floor.  ``lb`` from the host is the price-free
     # per-chunk-pass base min_d(W(d)/d) (times _LB_MARGIN); every worker
@@ -666,16 +570,7 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
         t0 = k * TILE
         zero = jnp.zeros_like(t0)
         with jax.named_scope("decide.rows"):
-            if use_cache:
-                tile_ok = jnp.all(
-                    jax.lax.dynamic_slice_in_dim(valid_tiles, k, 1, axis=1))
-                rows_tile = jax.lax.cond(
-                    tile_ok,
-                    lambda: jax.lax.dynamic_slice(
-                        rows_init, (zero, t0, zero), (B, TILE, M)),
-                    lambda: rows_for_tile(t0))
-            else:
-                rows_tile = rows_for_tile(t0)
+            rows_tile = rows_for_tile(t0)
         u_tile = jax.lax.dynamic_slice(u, (zero, t0), (B, TILE))
         prev, best, best_t, cols, paths = jax.lax.platform_dependent(
             rows_tile, u_tile, prev, best, best_t, paths, t0, a, d_tot,
@@ -697,14 +592,9 @@ def _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, *, T: int,
     return best_t, best, rows_buf, cost_buf, k0, k_end, paths
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("T", "d1", "use_cache", "mono",
-                                    "use_tabs"))
-def _decide_tiled(sd, jd, tabs, rows_init, valid_tiles, T: int, d1: int,
-                  use_cache: bool, mono: int, use_tabs: bool):
-    return _decide_tiled_core(sd, jd, tabs, rows_init, valid_tiles, T=T,
-                              d1=d1, use_cache=use_cache, mono=mono,
-                              use_tabs=use_tabs)
+@functools.partial(jax.jit, static_argnames=("T", "d1", "mono"))
+def _decide_tiled(sd, jd, T: int, d1: int, mono: int):
+    return _decide_tiled_core(sd, jd, T=T, d1=d1, mono=mono)
 
 
 @jax.jit
@@ -912,79 +802,6 @@ def _decide_one(sd, jd, d1: int, use_pallas: bool):
 
 
 # ---------------------------------------------------------------------------
-# Row cache (incremental COST-row maintenance)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class RowCache:
-    """Per-job COST-row cache across price-state versions.
-
-    ``rows`` holds the (T_pad, m_pad) COST_t table the engine computed
-    at ``version``; ``valid`` marks which ``TILE``-slot blocks of it are
-    both *visited* (actually computed, not the identity placeholder) and
-    *fresh* (no commit/release has moved prices inside them since).  The
-    engine recomputes exactly the invalid tiles (``use_cache`` path of
-    ``_decide_tiled``); :meth:`sync` invalidates against the price
-    state's dirty-slot log (``PriceState.dirty_spans_since``).
-
-    ``tables`` is the job's sorted-order/cumsum table set (6 arrays
-    (T_pad, H|K) from ``_sorted_fill_lanes``) at ``tables_version``.  It
-    is NOT maintained by :meth:`sync`: ``_decide_jobs`` patches exactly
-    the slots ``PriceState.patch_spans(tables_version)`` reports dirty
-    (``_sorted_fill``) right before each launch, so re-solves pay an
-    O(dirty) sort bill instead of re-sorting the horizon."""
-    rows: Optional[jax.Array]       # (T_pad, m_pad) device-resident
-    valid: np.ndarray               # (n_tiles,) bool, host
-    version: int
-    m_pad: int
-    d1: int
-    # 6 x (T_pad, S) device-resident, or a lazy ``_LaneTabs`` view into
-    # the stacked launch build (materialized via ``_tabs_get`` on reuse)
-    tables: Optional[object] = None
-    tables_version: int = -1
-
-    @classmethod
-    def empty(cls, state: PriceState, job: Job) -> Optional["RowCache"]:
-        """A cache with no valid tiles (first decision fills it).  None
-        for dcap-0 jobs (the engine rejects those without solving)."""
-        key = _shape_bucket(job)
-        if key is None:
-            return None
-        m_pad, d1 = key
-        n_tiles = _pad_tiles(state.horizon) // TILE
-        return cls(rows=None, valid=np.zeros(n_tiles, bool),
-                   version=state.version, m_pad=m_pad, d1=d1)
-
-    def invalidate_spans(self, spans) -> None:
-        """Mark every tile overlapping a dirtied [t0, t1) slot span stale."""
-        for t0, t1 in spans:
-            k0 = max(int(t0) // TILE, 0)
-            k1 = min((int(t1) - 1) // TILE + 1, len(self.valid))
-            self.valid[k0:k1] = False
-
-    def invalidate_all(self) -> None:
-        self.valid[:] = False
-
-    def sync(self, state: PriceState) -> "RowCache":
-        """Invalidate whatever ``state`` has dirtied since ``version``.
-
-        Uses the commit/release dirty-slot log; an unknown delta (window
-        slide, log trimmed) invalidates everything.  Returns self."""
-        if state.version != self.version:
-            spans = state.dirty_spans_since(self.version)
-            if spans is None:
-                self.invalidate_all()
-                if _obs.ENABLED:
-                    _obs.inc("decide.row_cache_full_invalidations")
-            else:
-                self.invalidate_spans(spans)
-            self.version = state.version
-            if _obs.ENABLED:
-                _obs.inc("decide.row_cache_syncs")
-        return self
-
-
-# ---------------------------------------------------------------------------
 # Python wrappers: padding, bucketing, Schedule construction
 # ---------------------------------------------------------------------------
 
@@ -1072,9 +889,8 @@ def _padded_state(state: PriceState, dtype, T_pad: int):
 
     Between consecutive versions the cache is patched incrementally:
     ``PriceState.dirty_spans_since`` names the slots the commits touched
-    and ``_pad_patch`` refreshes just those rows (the same maintenance
-    contract ``RowCache`` uses).  Falls back to a full re-pad when the
-    delta is unknowable or fragmented."""
+    and ``_pad_patch`` refreshes just those rows.  Falls back to a full
+    re-pad when the delta is unknowable or fragmented."""
     g, v, wcaps, scaps, U1, U2, L1, L2 = _state_arrays(state, dtype)
     key = (state.version, T_pad, jnp.dtype(dtype).name)
     hit = _pad_cache.get(state)
@@ -1241,7 +1057,6 @@ class _Pending:
     t_start: int                    # first slot the decision loop visited
     W: np.ndarray                   # (dcap+1,) workload tables
     Z: np.ndarray
-    cache: RowCache
     cost: float = float("nan")      # filled by _materialize for accepts
 
 
@@ -1331,116 +1146,6 @@ def _schedule_from_outputs(job: Job, state: PriceState, best_t: int,
                     utility=utility)
 
 
-@functools.lru_cache(maxsize=32)
-def _empty_cache(b_pad: int, T_pad: int, n_tiles: int, m_pad: int,
-                 dtype_name: str):
-    """Device-resident all-invalid row cache, one per launch shape: lets
-    the cache-less decision path run the ``use_cache=True`` compiled
-    variant without uploading a fresh buffer per launch."""
-    rows0 = np.zeros((b_pad, T_pad, m_pad))
-    rows0[:, :, 1:] = np.inf
-    return (jnp.asarray(rows0, jnp.dtype(dtype_name)),
-            jnp.zeros((b_pad, n_tiles), bool))
-
-
-class _LaneTabs:
-    """Deferred per-lane view into a stacked table set.
-
-    A fresh ``_sorted_fill_lanes`` launch returns six ``(B, T_pad, S)``
-    arrays; slicing every lane's 6-tuple out of them eagerly costs six
-    device ``__getitem__`` dispatches per lane, and in the streaming
-    engine nearly every launch is fresh while the slices are consumed
-    only if that job is later re-solved.  This holds (stack, lane) and
-    materializes the 6-tuple on first :meth:`get`."""
-
-    __slots__ = ("stack", "lane", "_tabs")
-
-    def __init__(self, stack: tuple, lane: int):
-        self.stack = stack
-        self.lane = lane
-        self._tabs: Optional[tuple] = None
-
-    def get(self) -> tuple:
-        if self._tabs is None:
-            bi = self.lane
-            self._tabs = tuple(t[bi] for t in self.stack)
-            self.stack = None
-        return self._tabs
-
-
-def _tabs_get(tabs) -> tuple:
-    """Materialize a RowCache ``tables`` entry (concrete or _LaneTabs)."""
-    return tabs.get() if isinstance(tabs, _LaneTabs) else tabs
-
-
-def _lane_tables(chunk, caches, state, psd, lanes, b_pad, T, dtype):
-    """Sorted-order/cumsum tables for every lane of one launch.
-
-    Serves each lane from its RowCache when fresh, patches it through
-    ``_sorted_fill`` when ``PriceState.patch_spans`` can name the dirty
-    slots (O(dirty) sort cost on the re-solve path), and rebuilds from
-    the cached price tables otherwise (one fused ``_sorted_fill_lanes``
-    launch).  Tables only exist at all below the ``_table_max``
-    footprint gate — above it the launch keeps the inline per-tile path
-    and this returns dummies.  Returns (tabs — 6 launch operands,
-    lane_tabs — per-lane entries (6-tuple, ``_LaneTabs``, or None) for
-    cache write-back, use_tabs — whether the launch slices ``tabs``)."""
-    g_pad, v_pad, wcaps, scaps = psd[0], psd[1], psd[2], psd[3]
-    p_pad, q_pad = psd[9], psd[10]
-    T_pad = g_pad.shape[0]
-    if T_pad * max(g_pad.shape[1], v_pad.shape[1]) > _table_max():
-        return _dummy_tabs(jnp.dtype(dtype).name), [None] * b_pad, False
-    lane_tabs: List[Optional[object]] = [None] * b_pad
-    for bi, (i, _) in enumerate(chunk):
-        cache = caches.get(i) if caches else None
-        if cache is None or cache.tables is None:
-            continue
-        if cache.tables_version == state.version:
-            lane_tabs[bi] = cache.tables
-            continue
-        spans = state.patch_spans(cache.tables_version,
-                                  limit=_PATCH_MAX_SPANS)
-        if spans is None:
-            continue
-        tabs_l = _tabs_get(cache.tables)
-        resbw = jnp.asarray(lanes[bi][0], dtype)
-        for s0, s1 in spans:
-            span = _bucket(max(s1 - s0, 1), floor=8, step=64)
-            if span > T:
-                tabs_l = None
-                break
-            start = min(max(int(s0), 0), T - span)
-            with _obs.dispatch("_sorted_fill"):
-                tabs_l = _sorted_fill(tabs_l, p_pad, q_pad, g_pad, v_pad,
-                                      wcaps, scaps, resbw,
-                                      jnp.int32(start), span)
-        lane_tabs[bi] = tabs_l
-    if all(t is None for t in lane_tabs):
-        resbw_all = jnp.asarray(np.stack([la[0] for la in lanes]), dtype)
-        with _obs.dispatch("_sorted_fill_lanes"):
-            full = _sorted_fill_lanes(p_pad, q_pad, g_pad, v_pad, wcaps,
-                                      scaps, resbw_all)
-        return full, [_LaneTabs(full, bi) for bi in range(b_pad)], True
-    for bi in range(b_pad):
-        if lane_tabs[bi] is None:
-            resbw = jnp.asarray(lanes[bi][0], dtype)
-            with _obs.dispatch("_sorted_fill_lanes"):
-                one = _sorted_fill_lanes(p_pad, q_pad, g_pad, v_pad, wcaps,
-                                         scaps, resbw[None])
-            lane_tabs[bi] = _LaneTabs(one, 0)
-    if b_pad == 1:
-        lt = lane_tabs[0]
-        if isinstance(lt, _LaneTabs) and lt.stack is not None \
-                and lt.lane == 0 and lt.stack[0].shape[0] == 1:
-            tabs = lt.stack       # reuse the stacked build directly
-        else:
-            tabs = tuple(t[None] for t in _tabs_get(lt))
-    else:
-        mats = [_tabs_get(lt) for lt in lane_tabs]
-        tabs = tuple(jnp.stack([m[k] for m in mats]) for k in range(6))
-    return tabs, lane_tabs, True
-
-
 def _form_counter(forms: dict, x: jax.Array) -> str:
     """The obs counter of the form ``forms`` (``_GREEDY_COST_FORMS``,
     ``_SLOT_SCAN_FORMS``) gives the platform of ``x``'s device: the
@@ -1450,10 +1155,9 @@ def _form_counter(forms: dict, x: jax.Array) -> str:
 
 
 def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
-                 m_pad: int, d1: int,
-                 caches: Optional[dict] = None) -> List[_Pending]:
+                 m_pad: int, d1: int) -> List[_Pending]:
     """Run the tiled core over one shape-bucket group (<= _MAX_LANES jobs
-    per launch).  ``caches``: optional {index: RowCache} serving lanes."""
+    per launch)."""
     T = state.horizon
     T_pad = _pad_tiles(T)
     n_tiles = T_pad // TILE
@@ -1474,43 +1178,6 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
                 lanes.append(la)
                 tables.append(wz)
             jd = _stack_lanes(lanes, dtype)
-            # the no-cache case runs the SAME compiled variant with an
-            # all-invalid (device-cached) empty cache: every distinct
-            # (shape, use_cache) pair is a separate multi-second XLA
-            # compilation, and the cond-per-tile overhead of the cached
-            # variant is microseconds
-            use_cache = caches is not None and any(
-                caches.get(i) is not None for i, _ in chunk)
-            if use_cache:
-                rows0 = np.zeros((b_pad, T_pad, m_pad))
-                rows0[:, :, 1:] = np.inf
-                valid0 = np.zeros((b_pad, n_tiles), bool)
-                rows_list = [None] * b_pad
-                for bi, (i, _) in enumerate(chunk):
-                    cache = caches.get(i)
-                    if cache is not None and cache.rows is not None:
-                        rows_list[bi] = cache.rows
-                        valid0[bi] = cache.valid
-                base = jnp.asarray(rows0, dtype)
-                stackable = [rows_list[bi] if rows_list[bi] is not None
-                             else base[bi] for bi in range(b_pad)]
-                rows_init = jnp.stack(stackable)
-                valid_tiles = jnp.asarray(valid0)
-                if _obs.ENABLED:
-                    _obs.inc("decide.cache_tiles_valid",
-                             int(valid0[:len(chunk)].sum()))
-                    _obs.inc("decide.cache_tiles_total",
-                             len(chunk) * n_tiles)
-            else:
-                # cache-less launches stay out of the cache_tiles_*
-                # counters: the tracked hit rate measures how much of a
-                # RE-SOLVE the row cache saved, not how often the cache
-                # path ran at all
-                rows_init, valid_tiles = _empty_cache(
-                    b_pad, T_pad, n_tiles, m_pad, jnp.dtype(dtype).name)
-            tabs, lane_tabs, use_tabs = _lane_tables(chunk, caches, state,
-                                                     sd, lanes, b_pad, T,
-                                                     dtype)
         mono = 0
         if b_pad == 1 and m_pad <= _mono_band():
             mono = 2 if _mono_dnc() else 1
@@ -1518,9 +1185,7 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
                        m_pad=m_pad) as dp_span:
             with _obs.dispatch("_decide_tiled"):
                 best_t, payoff, rows_buf, cost_buf, k0, k_end, paths = \
-                    _decide_tiled(sd, jd, tabs, rows_init, valid_tiles,
-                                  T=T, d1=d1, use_cache=True, mono=mono,
-                                  use_tabs=use_tabs)
+                    _decide_tiled(sd, jd, T=T, d1=d1, mono=mono)
             with _obs.span("launch.fetch", program="_decide_tiled"):
                 best_t, payoff, k0, k_end, pth = jax.device_get(
                     (best_t, payoff, k0, k_end, paths))
@@ -1529,12 +1194,7 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
         if _obs.ENABLED:
             _obs.inc("decide.tiles_visited", k_end - k0)
             _obs.inc("decide.tiles_horizon", n_tiles)
-            # a visited tile's rows are served from the row cache only
-            # where every lane holds it (``_decide_tiled_core``)
-            served = (int(valid0[:, k0:k_end].all(axis=0).sum())
-                      if use_cache else 0)
-            _obs.inc(_form_counter(_GREEDY_COST_FORMS, rows_buf),
-                     k_end - k0 - served)
+            _obs.inc(_form_counter(_GREEDY_COST_FORMS, rows_buf), k_end - k0)
             _obs.inc(_form_counter(_SLOT_SCAN_FORMS, rows_buf), k_end - k0)
             _obs.observe("decide.early_exit_frac",
                          (k_end - k0) / max(n_tiles, 1))
@@ -1544,24 +1204,11 @@ def _decide_jobs(jobs: Sequence[Tuple[int, Job]], state: PriceState, dtype,
             _obs.inc("decide.path_dnc", int(pth[0]))
             _obs.inc("decide.path_plateau", int(pth[1]))
             _obs.inc("decide.path_chain", int(pth[2]))
-        # each lane's row cache: the inputs of its later re-solves
-        with _obs.span("decide.prep", lanes=len(chunk)):
-            for bi, (i, job) in enumerate(chunk):
-                valid = np.zeros(n_tiles, bool)
-                if use_cache and caches.get(i) is not None:
-                    valid |= caches[i].valid
-                valid[k0:k_end] = True
-                cache = RowCache(rows=rows_buf[bi], valid=valid,
-                                 version=state.version, m_pad=m_pad, d1=d1,
-                                 tables=lane_tabs[bi],
-                                 tables_version=(state.version
-                                                 if lane_tabs[bi] is not None
-                                                 else -1))
-                out.append(_Pending(
-                    job=job, best_t=int(best_t[bi]),
-                    payoff=float(payoff[bi]), rows_full=rows_buf,
-                    cost_full=cost_buf, lane=bi, t_start=k0 * TILE,
-                    W=tables[bi][0], Z=tables[bi][1], cache=cache))
+        for bi, (_, job) in enumerate(chunk):
+            out.append(_Pending(
+                job=job, best_t=int(best_t[bi]), payoff=float(payoff[bi]),
+                rows_full=rows_buf, cost_full=cost_buf, lane=bi,
+                t_start=k0 * TILE, W=tables[bi][0], Z=tables[bi][1]))
     return out
 
 
@@ -1605,16 +1252,12 @@ def _shape_bucket(job: Job) -> Optional[Tuple[int, int]]:
 
 def best_schedule_fused(job: Job, state: PriceState, *,
                         use_pallas: Optional[bool] = None,
-                        precision: str = "auto",
-                        row_cache: Optional[RowCache] = None
-                        ) -> Optional[Schedule]:
+                        precision: str = "auto") -> Optional[Schedule]:
     """Alg. 2 for one job through the fused jit engine.
 
-    The default path is the tiled early-exit core; ``row_cache`` (from a
-    previous decision for the SAME job, ``sync``-ed against the state)
-    lets it recompute only dirtied tiles.  ``use_pallas=True`` routes
-    through the legacy monolithic core with the Pallas sweep kernel (the
-    TPU path)."""
+    The default path is the tiled early-exit core; ``use_pallas=True``
+    routes through the legacy monolithic core with the Pallas sweep
+    kernel (the TPU path)."""
     key = _shape_bucket(job)
     if key is None:
         return None
@@ -1638,15 +1281,7 @@ def best_schedule_fused(job: Job, state: PriceState, *,
             return _schedule_from_outputs(
                 job, state, int(best_t), float(cost), int(d_left),
                 d_slots, y, z)
-        caches = {0: row_cache} if row_cache is not None else None
-        pend = _decide_jobs([(0, job)], state, dtype, m_pad, d1,
-                            caches=caches)[0]
-        if row_cache is not None:
-            row_cache.rows = pend.cache.rows
-            row_cache.valid = pend.cache.valid
-            row_cache.version = pend.cache.version
-            row_cache.tables = pend.cache.tables
-            row_cache.tables_version = pend.cache.tables_version
+        pend = _decide_jobs([(0, job)], state, dtype, m_pad, d1)[0]
         with _obs.span("decide.prep", jid=job.jid):
             sd = _state_arrays(state, dtype)
         return _materialize(pend, state, sd, dtype)
@@ -1659,8 +1294,8 @@ def decide_burst(jobs: Sequence[Job], state: PriceState, *,
     prices, one tiled launch per shape bucket (jobs are grouped by
     (dcap, workload) bucket so a small job is never padded up to the
     burst's largest DP table).  Returns per-job ``_Pending`` candidates —
-    decision + split + row cache, placement deferred to
-    ``_materialize`` — in input order (None for dcap-0 jobs).  Commit
+    the decision and the launch's row/cost tables, split and placement
+    deferred to ``_materialize`` — in input order (None for dcap-0 jobs).  Commit
     order / price updates are the caller's job (``OASiS.on_arrivals``
     re-solves any job whose prices moved).
 

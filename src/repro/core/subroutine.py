@@ -259,13 +259,12 @@ def cost_t_rows(job: Job, state: PriceState, p: np.ndarray, q: np.ndarray,
     prefix-sum greedy costs are whole-array ops — no per-slot Python loop.
 
     ``slots`` (sorted 1-D slot indices) restricts the computation to those
-    slots, returning ``(len(slots), dcap + 1)`` — the host-side form of
-    the partial recompute the fused engine's row cache does per dirty
-    tile, and bit-identical to ``cost_t_rows(...)[slots]``.
+    slots, returning ``(len(slots), dcap + 1)``, bit-identical to
+    ``cost_t_rows(...)[slots]``.
     """
     a = job.arrival
     # read-only access to the host mirrors (not the mutable ``g``/``v``
-    # views, which would drop the device residency and row caches)
+    # views, which would drop the device residency and the dirty log)
     if slots is None:
         g_s, v_s, p_s, q_s = state._g_host, state._v_host, p, q
     else:

@@ -55,8 +55,7 @@ def _payload():
         "obs": {"T": 192, "H": 10, "K": 10, "n_jobs": 64, "quick": False,
                 "counters": {"decide.decisions": 64,
                              "price.device_uploads": 1},
-                "derived": {"row_cache_hit_rate": 0.03,
-                            "early_exit_frac": 0.4,
+                "derived": {"early_exit_frac": 0.4,
                             "device_uploads": 1, "preempted": 2}},
     }
 

@@ -224,13 +224,12 @@ def test_section_missing_entirely_does_not_phantom_refuse():
     assert check(fresh, base, ratio=2.0) == 0
 
 
-def _with_obs(doc, quick=False, obs_T=192, hit=0.03, early=0.4, uploads=1):
+def _with_obs(doc, quick=False, obs_T=192, early=0.4, uploads=1):
     doc["obs"] = {"T": obs_T, "H": 10, "K": 10, "n_jobs": 64,
                   "quick": quick,
                   "counters": {"decide.decisions": 64.0,
                                "engine.preemptions": 2.0},
-                  "derived": {"row_cache_hit_rate": hit,
-                              "early_exit_frac": early,
+                  "derived": {"early_exit_frac": early,
                               "device_uploads": uploads,
                               "preempted": 2.0}}
     return doc
@@ -238,25 +237,17 @@ def _with_obs(doc, quick=False, obs_T=192, hit=0.03, early=0.4, uploads=1):
 
 def test_obs_leaves_split_by_direction():
     """The flight-recorder derived figures: early_exit_frac and
-    device_uploads gate lower-is-better, row_cache_hit_rate inverted;
-    preempted and the raw counters are informational — no leaves."""
+    device_uploads gate lower-is-better, none inverted; preempted and the
+    raw counters are informational — no leaves."""
     doc = _with_obs(_doc())
     paths = dict(_leaves(doc))
     assert paths["obs.derived.early_exit_frac"] == 0.4
     assert paths["obs.derived.device_uploads"] == 1
     rates = dict(_rate_leaves(doc))
-    assert rates["obs.derived.row_cache_hit_rate"] == 0.03
+    assert not any(p.startswith("obs.") for p in rates)
     every = {**paths, **rates}
     assert not any("preempted" in p for p in every)
     assert not any("counters" in p for p in every)
-
-
-def test_obs_hit_rate_drop_gates_inverted():
-    base = _with_obs(_doc())
-    collapsed = _with_obs(_doc(), hit=0.01)       # 3x drop: cache broke
-    assert check(base, collapsed, ratio=2.0) == 1
-    better = _with_obs(_doc(), hit=0.3)           # improvement: fine
-    assert check(base, better, ratio=2.0) == 0
 
 
 def test_obs_efficiency_regression_gates():
@@ -279,4 +270,4 @@ def test_v4_baseline_without_obs_not_gated():
     section) must neither refuse nor gate the new derived leaves."""
     base = _doc()
     base["schema"] = "bench_decision/v4"
-    assert check(base, _with_obs(_doc(), hit=0.0001), ratio=2.0) == 0
+    assert check(base, _with_obs(_doc(), early=0.99), ratio=2.0) == 0

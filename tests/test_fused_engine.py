@@ -21,6 +21,8 @@ from repro.core.subroutine import (_greedy_cost_for_counts, cost_t_rows,
 from repro.core.types import ClusterSpec, Job, SigmoidUtility
 from repro.sim import make_cluster, make_jobs, simulate
 
+from random_ops import apply_random_ops
+
 
 def mk_job(jid=0, a=0, E=2, N=3, M=10, tau=0.02, e=0.05, b=1.0, B=4.0,
            g=(50.0, 1.0, 3.0), w=None, s=None):
@@ -58,6 +60,31 @@ def test_fused_equals_ref_randomized(seed, T, H, K):
         assert_same_decision(job, state, ref, got)
         if ref is not None:
             state.commit(job, ref.workers, ref.ps)
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("window", [None, 16])
+def test_resolve_after_interleaved_ops_equals_ref(seed, window):
+    """Decisions after rounds of random commits, releases and window
+    slides: the fused engine reads the padded price state that
+    ``_padded_state`` patches span by span (``_pad_patch``) or re-pads,
+    and must keep agreeing with the ref oracle round after round."""
+    T = 24
+    cluster = make_cluster(T=T, H=3, K=3)
+    jobs = make_jobs(6, T=T, seed=100 + seed, small=True)
+    params = price_params_from_jobs(jobs, cluster)
+    state = PriceState(cluster, params, window=window)
+    rng = np.random.default_rng(seed)
+    committed = []
+    for _ in range(5):
+        for job in jobs:
+            job = dataclasses.replace(job,
+                                      arrival=job.arrival % state.horizon)
+            ref = best_schedule_ref(job, state)
+            got = best_schedule_fused(job, state)
+            assert_same_decision(job, state, ref, got)
+        apply_random_ops(rng, state, jobs, committed, n_ops=3,
+                         allow_advance=window is not None)
 
 
 def test_fused_pallas_sweep_path_equals_ref():
@@ -167,10 +194,12 @@ def test_vectorized_rows_match_seed_loop():
         assert np.all(both_inf | (np.abs(fast - loop) < 1e-9)), job.jid
 
 
-def test_on_arrivals_equals_sequential_on_arrival():
+@pytest.mark.parametrize("T,H,K", [(18, 5, 5), (40, 8, 8), (64, 6, 10)])
+@pytest.mark.parametrize("seed", [13, 14])
+def test_on_arrivals_equals_sequential_on_arrival(T, H, K, seed):
     """Batched admission == sequential Alg. 1, job for job."""
-    cluster = make_cluster(T=18, H=5, K=5)
-    jobs = make_jobs(20, T=18, seed=13, small=True)
+    cluster = make_cluster(T=T, H=H, K=K)
+    jobs = make_jobs(20, T=T, seed=seed, small=True)
     params = price_params_from_jobs(jobs, cluster)
     seq = OASiS(cluster, params, impl="jax")
     for j in sorted(jobs, key=lambda x: (x.arrival, x.jid)):
@@ -185,6 +214,42 @@ def test_on_arrivals_equals_sequential_on_arrival():
     assert bat.total_utility == pytest.approx(seq.total_utility, rel=1e-9)
     for jid, s in seq.accepted.items():
         assert bat.accepted[jid].finish == s.finish
+
+
+def _slotted_run(cluster, jobs):
+    """``on_arrivals`` over each slot's arrivals, in jid order."""
+    bat = OASiS(cluster, price_params_from_jobs(jobs, cluster), impl="jax")
+    by_slot = {}
+    for j in jobs:
+        by_slot.setdefault(j.arrival, []).append(j)
+    for t in range(cluster.T):
+        bat.on_arrivals(sorted(by_slot.get(t, []), key=lambda x: x.jid))
+    return bat
+
+
+@pytest.mark.parametrize("T,H,K", [(18, 5, 5), (64, 6, 10)])
+def test_eight_lane_burst_equals_single_lane(monkeypatch, T, H, K):
+    """A burst decided in one 8-lane launch (the TPU's lane count) makes
+    the same decisions and placements, bit for bit, as the same burst
+    decided lane by lane (the CPU's)."""
+    from repro.core import schedule_jax
+    cluster = make_cluster(T=T, H=H, K=K)
+    jobs = make_jobs(2 * T, T=T, seed=13, small=True)
+    monkeypatch.setattr(schedule_jax, "_MAX_LANES", 1)
+    one = _slotted_run(cluster, jobs)
+    monkeypatch.setattr(schedule_jax, "_MAX_LANES", 8)
+    eight = _slotted_run(cluster, jobs)
+    assert len(one.accepted) > 0
+    assert set(one.accepted) == set(eight.accepted)
+    assert sorted(one.rejected) == sorted(eight.rejected)
+    assert eight.total_utility == one.total_utility       # exact
+    for jid, s in one.accepted.items():
+        b = eight.accepted[jid]
+        assert (b.finish, b.cost) == (s.finish, s.cost), jid
+        assert set(b.workers) == set(s.workers), jid
+        for t in s.workers:
+            assert np.array_equal(b.workers[t], s.workers[t]), (jid, t)
+            assert np.array_equal(b.ps[t], s.ps[t]), (jid, t)
 
 
 def test_simulator_capacity_sweep_jax_impl():

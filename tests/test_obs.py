@@ -342,8 +342,8 @@ def test_obs_export_embeds_metrics(tmp_path):
 # ---------------------------------------------------------------------------
 
 # spans that belong to one job carry its id
-_PER_JOB = ("decide", "decide.resolve", "decide.row_cache_sync",
-            "decide.backtrack", "decide.placement", "price.commit")
+_PER_JOB = ("decide", "decide.resolve", "decide.backtrack",
+            "decide.placement", "price.commit")
 
 
 def test_jax_stream_run_records_the_launch_boundary():
@@ -500,9 +500,8 @@ def _scope_cases():
     one_jd = (s(2 * R + 2), s(2, M, dtype=i32), s(T), s(3, dtype=i32))
     return {
         "_decide_tiled": (lambda: sj._decide_tiled.lower(
-            padded, tiled_jd, (s(1, 1, 1),) * 6, s(B, T, M),
-            s(B, T // 64, dtype=jnp.bool_), T=T, d1=D1, use_cache=True,
-            mono=0, use_tabs=False), ("decide.rows", "decide.dp")),
+            padded, tiled_jd, T=T, d1=D1, mono=0),
+            ("decide.rows", "decide.dp")),
         "_decide_one": (lambda: sj._decide_one.lower(
             state, one_jd, d1=D1, use_pallas=False),
             ("decide.rows", "decide.dp", "decide.backtrack",
@@ -515,15 +514,15 @@ def _scope_cases():
             ("decide.placement",)),
         "_pad_state": (lambda: sj._pad_state.lower(*state, T_pad=T),
                        ("price.pad",)),
-        "_sorted_fill_lanes": (lambda: sj._sorted_fill_lanes.lower(
-            s(T, H, R), s(T, K, R), s(T, H, R), s(T, K, R), s(H, R),
-            s(K, R), s(B, 2 * R + 2)), ("decide.rows",)),
+        "_pad_patch": (lambda: sj._pad_patch.lower(
+            *padded[:2], padded[8], *padded[9:], *state,
+            s(dtype=i32), span=8), ("price.pad",)),
     }
 
 
 @pytest.mark.parametrize("program", ["_decide_tiled", "_decide_one",
                                      "_backtrack", "_place_slots",
-                                     "_pad_state", "_sorted_fill_lanes"])
+                                     "_pad_state", "_pad_patch"])
 def test_decide_programs_carry_their_device_scopes(program):
     lower, scopes = _scope_cases()[program]
     text = lower().compile().as_text()

@@ -1,12 +1,9 @@
 """Row-cache invalidation properties.
 
-The incremental COST-row path has two halves that must agree with the
-from-scratch oracle after ANY interleaving of price-state mutations:
-
-* host: ``PriceState.dirty_spans_since`` + ``cost_t_rows(..., slots=...)``
-  must reconstruct exactly ``cost_t_rows`` recomputed from scratch;
-* device: ``RowCache.sync`` + ``best_schedule_fused(row_cache=...)`` must
-  make bit-identical decisions to the cache-free fused engine.
+The incremental host COST-row path must agree with the from-scratch
+oracle after ANY interleaving of price-state mutations:
+``PriceState.dirty_spans_since`` + ``cost_t_rows(..., slots=...)`` must
+reconstruct exactly ``cost_t_rows`` recomputed from scratch.
 
 A seeded randomized sweep always runs; the hypothesis variant (optional
 dev dependency, requirements-dev.txt) explores adversarial interleavings
@@ -20,33 +17,7 @@ from repro.core.pricing import PriceState
 from repro.core.subroutine import cost_t_rows
 from repro.sim import make_cluster, make_jobs
 
-
-def _rand_alloc(rng, T, S, max_count=2):
-    """A random slot->counts allocation dict over a contiguous range."""
-    t0 = int(rng.integers(0, T))
-    t1 = int(rng.integers(t0, min(t0 + 6, T)))
-    return {t: rng.integers(0, max_count + 1, size=S).astype(np.int64)
-            for t in range(t0, t1 + 1)}
-
-
-def _apply_random_ops(rng, state, jobs, committed, n_ops, allow_advance):
-    """Mutate ``state`` with a random commit/release/advance sequence."""
-    T = state.horizon
-    H, K = state.cluster.H, state.cluster.K
-    for _ in range(n_ops):
-        op = rng.integers(0, 3 if allow_advance else 2)
-        if op == 0:                                # commit
-            job = jobs[int(rng.integers(0, len(jobs)))]
-            w = _rand_alloc(rng, T, H)
-            z = _rand_alloc(rng, T, K, max_count=1)
-            state.commit(job, w, z)
-            committed.append((job, w, z))
-        elif op == 1 and committed:                # release an earlier commit
-            job, w, z = committed.pop(int(rng.integers(0, len(committed))))
-            state.release(job, w, z)
-        elif op == 2:                              # slide the window
-            state.advance(state.origin + int(rng.integers(1, 4)))
-            committed.clear()                      # slots re-indexed
+from random_ops import apply_random_ops
 
 
 def _host_roundtrip(seed: int, window, n_rounds: int = 6, n_ops: int = 3):
@@ -70,8 +41,8 @@ def _host_roundtrip(seed: int, window, n_rounds: int = 6, n_ops: int = 3):
     version = state.version
     committed = []
     for _ in range(n_rounds):
-        _apply_random_ops(rng, state, jobs, committed, n_ops,
-                          allow_advance=window is not None)
+        apply_random_ops(rng, state, jobs, committed, n_ops,
+                         allow_advance=window is not None)
         spans = state.dirty_spans_since(version)
         p, q = state.worker_prices(), state.ps_prices()
         if spans is None:                          # unknowable: full rebuild
@@ -90,38 +61,6 @@ def _host_roundtrip(seed: int, window, n_rounds: int = 6, n_ops: int = 3):
 @pytest.mark.parametrize("window", [None, 16])
 def test_host_row_cache_randomized(seed, window):
     _host_roundtrip(seed, window)
-
-
-@pytest.mark.parametrize("seed", range(3))
-def test_device_row_cache_randomized(seed):
-    """Cache-served fused decisions == cache-free fused decisions, bit for
-    bit, across interleaved commits/releases/advances."""
-    from repro.core.schedule_jax import RowCache, best_schedule_fused
-    T = 24
-    cluster = make_cluster(T=T, H=3, K=3)
-    jobs = make_jobs(6, T=T, seed=100 + seed, small=True)
-    params = price_params_from_jobs(jobs, cluster)
-    state = PriceState(cluster, params)
-    job = jobs[0]
-    cache = RowCache.empty(state, job)
-    if cache is None:
-        pytest.skip("degenerate job")
-    rng = np.random.default_rng(seed)
-    committed = []
-    for rounds in range(5):
-        cache.sync(state)
-        got = best_schedule_fused(job, state, row_cache=cache)
-        want = best_schedule_fused(job, state)
-        assert (got is None) == (want is None), seed
-        if want is not None:
-            assert got.finish == want.finish
-            assert got.cost == want.cost           # bit-identical
-            assert got.payoff == want.payoff
-            for t in want.workers:
-                assert np.array_equal(got.workers[t], want.workers[t])
-                assert np.array_equal(got.ps[t], want.ps[t])
-        _apply_random_ops(rng, state, jobs, committed, n_ops=3,
-                          allow_advance=rounds == 3)
 
 
 def test_dirty_span_log_semantics():
@@ -152,113 +91,6 @@ def test_dirty_span_log_semantics():
     assert state.dirty_spans_since(v1) is None
 
 
-def test_row_cache_reuses_valid_tiles():
-    """After sync, only tiles overlapping the dirty spans are invalid."""
-    from repro.core.schedule_jax import TILE, RowCache, best_schedule_fused
-    T = 2 * TILE + 2                               # multi-tile horizon
-    cluster = make_cluster(T=T, H=3, K=3)
-    jobs = make_jobs(6, T=T, seed=2, small=True)
-    params = price_params_from_jobs(jobs, cluster)
-    state = PriceState(cluster, params)
-    job = jobs[0]
-    cache = RowCache.empty(state, job)
-    assert cache is not None and len(cache.valid) >= 3
-    assert not cache.valid.any()
-    best_schedule_fused(job, state, row_cache=cache)
-    assert cache.valid.any()                       # visited tiles recorded
-    valid_before = cache.valid.copy()
-    # a commit inside tile 0 dirties only tile 0
-    state.commit(jobs[1], {1: np.array([1, 0, 0], np.int64)}, {})
-    cache.sync(state)
-    assert not cache.valid[0]
-    assert np.array_equal(cache.valid[1:], valid_before[1:])
-
-
-def _table_roundtrip(seed: int, n_rounds: int = 4, n_ops: int = 3,
-                     drop_residency: bool = False):
-    """Order-cache property: the sorted-order/cumsum tables the engine
-    leaves in ``RowCache.tables`` — span-patched via ``_sorted_fill`` on
-    re-solves, or served stale-free from a fresh build — must equal a
-    from-scratch ``_sorted_fill_lanes`` re-sort at the current state
-    version, bit for bit, after ANY interleaving of commit/release/
-    advance.  ``drop_residency=True`` touches the host-mutable ``state.g``
-    between rounds so ``patch_spans`` turns unknowable and the rebuild
-    (rather than patch) path is the one under test."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core import schedule_jax as S
-
-    T = 24
-    cluster = make_cluster(T=T, H=3, K=3)
-    jobs = make_jobs(6, T=T, seed=seed % 997, small=True)
-    params = price_params_from_jobs(jobs, cluster)
-    state = PriceState(cluster, params)
-    job = jobs[0]
-    cache = S.RowCache.empty(state, job)
-    if cache is None:
-        pytest.skip("degenerate job")
-    rng = np.random.default_rng(seed)
-    committed = []
-    with S._x64_context("auto"):
-        dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-        for rounds in range(n_rounds):
-            cache.sync(state)
-            S.best_schedule_fused(job, state, row_cache=cache)
-            if cache.tables is None:
-                pytest.skip("order-cache footprint gate off at this shape")
-            assert cache.tables_version == state.version, (seed, rounds)
-            got = tuple(np.asarray(t) for t in S._tabs_get(cache.tables))
-            # from-scratch reference at the CURRENT state: one fused
-            # full-table build over this job's lane
-            T_now = state.horizon
-            T_pad = S._pad_tiles(T_now)
-            m_pad, _ = S._shape_bucket(job)
-            psd = S._padded_state(state, dtype, T_pad)
-            la, _ = S._job_arrays_tiled(job, state, T_now, T_pad, m_pad,
-                                        dtype)
-            resbw = jnp.asarray(la[0], dtype)
-            full = S._sorted_fill_lanes(psd[9], psd[10], psd[0], psd[1],
-                                        psd[2], psd[3], resbw[None])
-            want = tuple(np.asarray(t[0]) for t in full)
-            for k, (g_t, w_t) in enumerate(zip(got, want)):
-                assert np.array_equal(g_t, w_t), (seed, rounds, k)
-            _apply_random_ops(rng, state, jobs, committed, n_ops,
-                              allow_advance=rounds == n_rounds - 2)
-            if drop_residency:
-                _ = state.g      # host access: spans unknowable -> rebuild
-
-
-@pytest.mark.parametrize("seed", range(3))
-@pytest.mark.parametrize("drop_residency", [False, True])
-def test_order_cache_tables_randomized(seed, drop_residency):
-    """Patched sorted-order/cumsum tables == full re-sorts (both the
-    device span-patch path and the residency-drop rebuild path)."""
-    _table_roundtrip(200 + seed, drop_residency=drop_residency)
-
-
-def test_order_cache_gate_off_keeps_inline_path(monkeypatch):
-    """Above the REPRO_ORDER_CACHE_MAX footprint the engine must not
-    build tables at all (the decide loop keeps the inline per-tile
-    argsorts) — and decisions stay bit-identical either way."""
-    from repro.core.schedule_jax import RowCache, best_schedule_fused
-    T = 24
-    cluster = make_cluster(T=T, H=3, K=3)
-    jobs = make_jobs(6, T=T, seed=7, small=True)
-    params = price_params_from_jobs(jobs, cluster)
-    state = PriceState(cluster, params)
-    job = jobs[0]
-    cache = RowCache.empty(state, job)
-    if cache is None:
-        pytest.skip("degenerate job")
-    want = best_schedule_fused(job, state)
-    monkeypatch.setenv("REPRO_ORDER_CACHE_MAX", "1")
-    got = best_schedule_fused(job, state, row_cache=cache)
-    assert cache.tables is None and cache.tables_version == -1
-    assert (got is None) == (want is None)
-    if want is not None:
-        assert got.cost == want.cost and got.finish == want.finish
-
-
 # -- hypothesis variant ------------------------------------------------------
 
 try:
@@ -275,13 +107,3 @@ if HAVE_HYPOTHESIS:
            n_ops=st.integers(1, 5))
     def test_host_row_cache_hypothesis(seed, window, n_rounds, n_ops):
         _host_roundtrip(seed, window, n_rounds=n_rounds, n_ops=n_ops)
-
-    @settings(max_examples=10, deadline=None)
-    @given(seed=st.integers(0, 2 ** 16),
-           n_rounds=st.integers(1, 5),
-           n_ops=st.integers(1, 4),
-           drop_residency=st.booleans())
-    def test_order_cache_tables_hypothesis(seed, n_rounds, n_ops,
-                                           drop_residency):
-        _table_roundtrip(seed, n_rounds=n_rounds, n_ops=n_ops,
-                         drop_residency=drop_residency)

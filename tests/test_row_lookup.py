@@ -165,8 +165,8 @@ def test_engine_with_tpu_row_lookup_equals_search(monkeypatch):
 
 def test_cpu_rows_counted_as_search_tiles():
     """On the CPU every COST-row tile a launch builds is counted under
-    ``decide.rows_search_tiles``: the visited tiles of a burst less those
-    its row cache served, and a ``_decide_one`` launch's whole horizon."""
+    ``decide.rows_search_tiles``: every visited tile of a burst or a
+    re-solve, and a ``_decide_one`` launch's whole horizon."""
     cluster = make_cluster(T=100, H=6, K=6)
     jobs = make_jobs(3, T=100, seed=2, small=True)
     state = PriceState(cluster, price_params_from_jobs(jobs, cluster))
@@ -178,16 +178,13 @@ def test_cpu_rows_counted_as_search_tiles():
     assert c["decide.rows_search_tiles"] == c["decide.tiles_visited"]
     assert c.get("decide.rows_dense_tiles", 0) == 0
 
-    # a re-solve at unchanged prices: every visited tile is served from
-    # the row cache, so no row is built
-    cache = sj.RowCache.empty(state, jobs[0])
-    sj.best_schedule_fused(jobs[0], state, row_cache=cache)
+    # a re-solve builds the rows of every tile it visits
     ob = obslib.Obs()
     with obslib.activate(ob):
-        sj.best_schedule_fused(jobs[0], state, row_cache=cache.sync(state))
+        sj.best_schedule_fused(jobs[0], state)
     c = ob.metrics.snapshot()["counters"]
     assert c["decide.tiles_visited"] > 0
-    assert c.get("decide.rows_search_tiles", 0) == 0
+    assert c["decide.rows_search_tiles"] == c["decide.tiles_visited"]
     assert c.get("decide.rows_dense_tiles", 0) == 0
 
     ob = obslib.Obs()

@@ -85,12 +85,7 @@ def _tiled_launch(sh, m_pad):
           _spec(sh, (LANES, 2, m_pad), jnp.int32),
           _spec(sh, (LANES, T_PAD)), _spec(sh, (LANES, T_PAD)),
           _spec(sh, (LANES, 4), jnp.int32), _spec(sh, (LANES,)))
-    tabs = (_spec(sh, (1, 1, 1)),) * 6
-    rows = _spec(sh, (LANES, T_PAD, m_pad))
-    valid = _spec(sh, (LANES, T_PAD // 64), jnp.bool_)
-    return _decide_tiled.lower(sd, jd, tabs, rows, valid, T=T, d1=D1,
-                               use_cache=True, mono=0,
-                               use_tabs=False).compile()
+    return _decide_tiled.lower(sd, jd, T=T, d1=D1, mono=0).compile()
 
 
 def test_legacy_decide_launch_holds_the_kernel(one_chip):
@@ -156,11 +151,7 @@ def test_tiled_slot_scan_on_the_cpu_holds_no_custom_call():
           _spec(None, (LANES, 2, m_pad), jnp.int32),
           _spec(None, (LANES, T_PAD)), _spec(None, (LANES, T_PAD)),
           _spec(None, (LANES, 4), jnp.int32), _spec(None, (LANES,)))
-    lowered = _decide_tiled.lower(
-        sd, jd, (_spec(None, (1, 1, 1)),) * 6,
-        _spec(None, (LANES, T_PAD, m_pad)),
-        _spec(None, (LANES, T_PAD // 64), jnp.bool_), T=T, d1=D1,
-        use_cache=True, mono=0, use_tabs=False)
+    lowered = _decide_tiled.lower(sd, jd, T=T, d1=D1, mono=0)
     assert "custom_call" not in lowered.as_text()
     assert "custom-call" not in lowered.compile().as_text()
 
